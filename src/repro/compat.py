@@ -1,12 +1,12 @@
-"""Shims for jax APIs that moved between 0.4.x and current releases.
+"""Mesh construction and the CPU virtual-device re-exec.
 
-The repo targets current jax (``jax.make_mesh(..., axis_types=...)``,
-``jax.set_mesh``, ``jax.shard_map``); older CPU containers pin 0.4.x
-where those live elsewhere or don't exist. Every call site goes through
-these helpers so both resolve identically.
+:func:`make_mesh` builds meshes with ``Auto`` axis types: the sharding
+rules (``distributed.sharding``) and the activation constraints
+(``DistContext.constrain``) are written for GSPMD propagation, while
+``jax.make_mesh`` defaults to ``Explicit`` axes.
 
-Also hosts :func:`ensure_host_device_count`, the CPU virtual-device
-shim the multi-device CLIs (``launch/serve --devices``,
+:func:`ensure_host_device_count` is the CPU virtual-device shim the
+multi-device CLIs (``launch/serve --devices``,
 ``benchmarks/serving.py --devices``) use to re-exec themselves with
 ``--xla_force_host_platform_device_count`` when asked for more devices
 than are attached.
@@ -17,25 +17,15 @@ from typing import Sequence
 
 import jax
 
-__all__ = ["ensure_host_device_count", "make_mesh", "set_mesh",
-           "shard_map"]
+__all__ = ["ensure_host_device_count", "make_mesh"]
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` (jax.set_mesh on current jax;
-    the Mesh object is itself a context manager on 0.4.x)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices, with
+    ``Auto`` axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axes))
 
 
 def ensure_host_device_count(n: int) -> None:
@@ -46,8 +36,13 @@ def ensure_host_device_count(n: int) -> None:
     parses ``--devices N``. When fewer than ``n`` devices are attached
     (and the backend is CPU), re-exec the same argv with the flag added
     to ``XLA_FLAGS`` and exit with the child's status. No-op when
-    enough devices already exist; raises on non-CPU backends (real
-    accelerators cannot be conjured) or if a re-exec already happened.
+    enough devices already exist; raises if a re-exec already happened.
+
+    The refusal of non-CPU backends is what makes the re-exec safe:
+    this process has already initialised its backend, and on an
+    accelerator it holds the chip until it exits, so a child that
+    needed the chip would fail or hang. Accelerators cannot be
+    conjured anyway — the raise names how many are attached.
     """
     import os
     import subprocess
@@ -69,24 +64,3 @@ def ensure_host_device_count(n: int) -> None:
         + f" --xla_force_host_platform_device_count={n}").strip()
     raise SystemExit(
         subprocess.call([sys.executable] + sys.argv, env=env))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=True):
-    if hasattr(jax, "shard_map"):
-        if not check_rep:
-            # pallas_call has no replication rule, so callers closing
-            # over kernels must disable the check; the kwarg was renamed
-            # check_vma and then dropped across releases — try each
-            for kw in ("check_rep", "check_vma"):
-                try:
-                    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                         out_specs=out_specs, **{kw: False})
-                except TypeError:
-                    continue
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # 0.4.x shard_map has no replication rule for checkpoint_name (used
-    # for the paper's t_di/t_m residual tags) — disable the rep check
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)

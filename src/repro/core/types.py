@@ -98,9 +98,30 @@ HW_SPECS: Dict[str, HardwareSpec] = {
 }
 
 
+# jax ``device_kind`` prefix -> HW_SPECS name. A TPU v5e reports
+# "TPU v5 lite"; kinds not listed here have no spec and are refused.
+DEVICE_KINDS: Tuple[Tuple[str, str], ...] = (
+    ("TPU v5 lite", "tpu-v5e"),
+    ("TPU v5e", "tpu-v5e"),
+    ("NVIDIA A100", "gpu-a100"),
+    ("cpu", "cpu-host"),
+)
+
+
+def hw_for_device_kind(kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a jax ``device_kind``; an unknown
+    kind is an error (planning a different chip as a v5e would rank
+    (n, strategy) candidates with the wrong peaks)."""
+    for prefix, name in DEVICE_KINDS:
+        if kind.startswith(prefix):
+            return HW_SPECS[name]
+    raise ValueError(f"no HardwareSpec for device kind {kind!r}; known "
+                     f"kinds: {[p for p, _ in DEVICE_KINDS]}")
+
+
 def resolve_hw(name: str = "auto") -> HardwareSpec:
-    """Named :class:`HardwareSpec`, or ``"auto"`` to detect from the
-    attached jax backend (tpu -> tpu-v5e, gpu -> gpu-a100, else cpu)."""
+    """Named :class:`HardwareSpec`, or ``"auto"`` to detect it from the
+    first attached device's ``device_kind``."""
     if name != "auto":
         try:
             return HW_SPECS[name]
@@ -109,6 +130,4 @@ def resolve_hw(name: str = "auto") -> HardwareSpec:
                            f"{sorted(HW_SPECS)} or 'auto'") from None
     import jax  # lazy: keep this module importable without a backend
 
-    platform = jax.devices()[0].platform
-    return HW_SPECS.get({"tpu": "tpu-v5e", "gpu": "gpu-a100"}
-                        .get(platform, "cpu-host"), CPU_HOST)
+    return hw_for_device_kind(jax.devices()[0].device_kind)

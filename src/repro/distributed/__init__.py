@@ -12,9 +12,9 @@ Module map
 ``compression.py``  int8 gradient compression with error feedback for
                     cross-pod reduction.
 
-Rule of the house: mesh and ``shard_map`` construction always goes
-through ``repro.compat`` (jax 0.4.x ↔ current shims), never ``jax.*``
-directly. See ``docs/distributed.md`` for the serving mesh layout.
+Meshes are built by ``repro.compat.make_mesh`` (``Auto`` axis types,
+which the sharding rules assume). See ``docs/distributed.md`` for the
+serving mesh layout.
 """
 from repro.distributed.context import (DistContext, constrain, ep_split,
                                        make_serving_context)

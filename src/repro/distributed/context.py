@@ -9,9 +9,8 @@ mesh directly.
 Serving: :func:`make_serving_context` builds the dp x ep mesh the
 continuous-batching engine (``repro.serve``) runs on — expert-parallel
 prefill through ``pipelined_moe``'s ``sharded`` layout, replicated
-psum-combine decode. Mesh construction goes through ``repro.compat``
-(never ``jax.*`` mesh calls directly) so jax 0.4.x and current resolve
-identically.
+psum-combine decode. Meshes come from ``repro.compat.make_mesh``
+(``Auto`` axis types).
 """
 from __future__ import annotations
 
@@ -126,7 +125,7 @@ def make_serving_context(devices: int, *,
     """Mesh + context for mesh-sharded serving (``repro.serve``).
 
     Builds a ``(data=dp, model=ep)`` mesh over the first ``devices``
-    jax devices via the ``repro.compat`` shims and returns a
+    jax devices (``repro.compat.make_mesh``) and returns a
     :class:`DistContext` with ``ep_axis="model"`` (expert parallelism
     only — ``tp_axis`` is None so attention stays unsharded and the
     paged-KV pools replicate). Returns None for ``devices <= 1`` — the

@@ -27,7 +27,7 @@ from repro.configs.base import ArchConfig, ShapeConfig
 from repro.models import module
 
 __all__ = ["AxisVal", "Rules", "batch_shardings", "cache_shardings",
-           "like_params", "make_rules", "param_shardings"]
+           "make_rules", "param_shardings"]
 
 AxisVal = Union[None, str, Tuple[str, ...]]
 
@@ -116,27 +116,6 @@ def param_shardings(cfg: ArchConfig, rules: Rules, model):
                                                is_leaf=module.is_spec)
     out = [rules.sharding_for(s.shape, s.axes) for s in flat]
     return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def like_params(param_shardings_tree, state_abstract):
-    """Sharding for optimizer state: broadcast each param's sharding onto
-    its (possibly nested) moment entries by shape-rank matching."""
-    def match(sh, leaf):
-        spec = sh.spec
-        nd = len(leaf.shape)
-        entries = list(spec) + [None] * max(0, nd - len(spec))
-        entries = entries[:nd]
-        # drop entries that no longer divide (e.g. factored vr/vc, q8 scale)
-        fixed = []
-        for dim, e in zip(leaf.shape, entries):
-            ext = 1
-            if e is not None:
-                axes = (e,) if isinstance(e, str) else e
-                for a in axes:
-                    ext *= sh.mesh.shape[a]
-            fixed.append(e if dim % ext == 0 and dim >= ext else None)
-        return NamedSharding(sh.mesh, P(*fixed))
-    return match
 
 
 def batch_shardings(cfg: ArchConfig, shape: ShapeConfig, rules: Rules,

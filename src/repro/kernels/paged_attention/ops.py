@@ -30,7 +30,6 @@ def _run_sharded(fn, dist, kv_sharded, qargs, pools, page_table, lens):
     """shard_map ``fn(*qargs, *pools, page_table, lens)`` over the dp
     axis; output is slot-sharded like ``qargs[0]``."""
     from jax.sharding import PartitionSpec as P
-    from repro import compat
 
     dp = dist.dp_axes[0]
 
@@ -48,12 +47,12 @@ def _run_sharded(fn, dist, kv_sharded, qargs, pools, page_table, lens):
         pt = pt % ps[0].shape[0]      # global -> shard-local page ids
         return fn(*qs, *ps, pt, ln)
 
-    wrapped = compat.shard_map(
+    wrapped = jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(tuple(slot_spec(a) for a in qargs)
                   + tuple(pool_spec(a) for a in pools)
                   + (slot_spec(page_table), slot_spec(lens))),
-        out_specs=slot_spec(qargs[0]), check_rep=False)
+        out_specs=slot_spec(qargs[0]), check_vma=False)
     return wrapped(*qargs, *pools, page_table, lens)
 
 

@@ -21,7 +21,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.configs import ARCHS, ASSIGNED, SHAPES, applicable, get_config
 from repro.core import TPU_V5E, resolve
 from repro.distributed.context import DistContext
@@ -30,7 +29,7 @@ from repro.launch import hlo_analysis, specs as specs_lib
 from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.models.api import get_model
 from repro.runtime.train_loop import TrainOptions, abstract_state, \
-    make_train_step
+    make_train_step, train_state_shardings
 
 
 def _dist_for(mesh) -> DistContext:
@@ -94,20 +93,12 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                          "strategy": cfg.moe.memory_reuse_strategy}
 
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pshard = shd.param_shardings(cfg, rules, model)
         if shape.kind == "train":
             opts = TrainOptions()
             astate = abstract_state(cfg, opts)
-            from repro.optim import get_optimizer, state_shardings
-            opt_mod, ocfg = get_optimizer(cfg.optimizer, opts.lr)
-            sshard = {
-                "params": pshard,
-                "opt": state_shardings(opt_mod, ocfg, astate["params"],
-                                       pshard, mesh),
-                "step": jax.sharding.NamedSharding(
-                    mesh, jax.sharding.PartitionSpec()),
-            }
+            sshard = train_state_shardings(cfg, opts, mesh)
             abatch = specs_lib.train_batch_specs(cfg, shape)
             bshard = shd.batch_shardings(cfg, shape, rules, abatch)
             step = make_train_step(cfg, opts, dist)

@@ -33,7 +33,11 @@ Two paths share one CLI:
   static.
 
 ``--hw`` names the :class:`HardwareSpec` the MPipeMoE resolver plans
-for; ``auto`` detects it from the attached jax backend.
+for; ``auto`` detects it from the first device's ``device_kind``.
+``--full`` serves the config at its published widths and refuses to
+start without an accelerator; the default is the reduced same-family
+config, which CPU runs use. The persistent compilation cache follows
+``launch.compile_cache``.
 
 Telemetry (engine path only; see docs/observability.md):
 ``--metrics-port`` serves live Prometheus ``/metrics`` + ``/healthz``
@@ -62,6 +66,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import HW_SPECS, resolve, resolve_hw
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
 
 
@@ -121,9 +126,10 @@ def legacy_loop(args, cfg, hw):
               f"(stopped early: {int(done.sum())})")
 
 
-def _engine_setup(args, cfg, hw):
-    """Shared by the replay and ingress engine paths: servability
-    check, recorder, EngineOptions from the CLI."""
+def engine_setup(args, cfg, hw):
+    """Shared by the replay and ingress engine paths (and
+    ``chip_smoke.py``): servability check, recorder, EngineOptions from
+    the CLI's arguments."""
     from repro.models.api import serving_support
     from repro.obs import Recorder, Tracer
     from repro.serve import EngineOptions
@@ -149,7 +155,7 @@ def ingress_loop(args, cfg, hw):
     from repro.obs import MetricsServer
     from repro.serve import Engine, IngressOptions, IngressServer
 
-    obs, opts = _engine_setup(args, cfg, hw)
+    obs, opts = engine_setup(args, cfg, hw)
     engine = Engine(cfg, None, options=opts)
     engine.warmup()
     server = None
@@ -187,7 +193,7 @@ def engine_loop(args, cfg, hw):
     from repro.obs import MetricsServer
     from repro.serve import SamplingParams, run_poisson
 
-    obs, opts = _engine_setup(args, cfg, hw)
+    obs, opts = engine_setup(args, cfg, hw)
     sampling = None
     if args.temperature > 0:
         sampling = SamplingParams(temperature=args.temperature,
@@ -256,9 +262,13 @@ def engine_loop(args, cfg, hw):
         print(f"  bucket {bucket:4d} -> n={n} strategy={strat}")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
+    ap.add_argument("--full", action="store_true",
+                    help="build the config at its published widths "
+                         "(needs an accelerator); default is the reduced "
+                         "same-family config")
     ap.add_argument("--batch", type=int, default=4,
                     help="legacy: batch size; engine: decode slots")
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -356,7 +366,19 @@ def main():
                     help="ingress: bound on requests accepted but not "
                          "yet finished before load shedding kicks in")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def config_for(args):
+    """The served config: published widths with ``--full``, else the
+    reduced same-family config."""
+    cfg = get_config(args.arch)
+    return cfg if args.full else cfg.reduced()
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
 
     if args.devices > 1:
         if not args.engine:
@@ -383,9 +405,13 @@ def main():
                                or args.admission_queue != 8):
         ap.error("--shed-policy / --admission-queue configure the "
                  "HTTP ingress tier; add --http-port")
+    if args.full and jax.default_backend() == "cpu":
+        ap.error("--full builds the published widths and needs an "
+                 "accelerator; jax found only CPU devices")
+    print(f"compile cache: {enable_compile_cache()}")
     hw = resolve_hw(args.hw)
     print(f"hw spec: {hw.name}")
-    cfg = get_config(args.arch).reduced()
+    cfg = config_for(args)
     if args.engine and args.http_port >= 0:
         ingress_loop(args, cfg, hw)
     elif args.engine:

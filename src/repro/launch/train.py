@@ -3,11 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch llama3-8b \
         --shape train_4k --steps 100 [--mesh-data 16 --mesh-model 16]
 
-On this CPU container it runs reduced configs on a small host mesh; on a
-real TPU pod the same entry point uses the production mesh (the step
-function, shardings and checkpointing are identical — only the mesh and
-config scale change). Fault tolerance: checkpoint/restart + seekable
-data + heartbeats (DESIGN §8).
+By default it runs the reduced (tiny, same-family) config, which is what
+CPU runs use; ``--full`` builds the published widths and refuses to
+start without an accelerator. With ``--mesh-data``/``--mesh-model`` the
+train state is created sharded over that mesh (experts over ``model``).
+The persistent compilation cache follows ``launch.compile_cache``.
+Fault tolerance: checkpoint/restart + seekable data + heartbeats
+(DESIGN §8).
 """
 from __future__ import annotations
 
@@ -16,14 +18,13 @@ import dataclasses
 import logging
 
 import jax
-import jax.numpy as jnp
 
 from repro.ckpt import Checkpointer
-from repro.compat import set_mesh
 from repro.configs import SHAPES, get_config
-from repro.core import TPU_V5E, resolve
+from repro.core import resolve, resolve_hw
 from repro.data import SyntheticTokens
 from repro.distributed.context import DistContext
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_axes, make_host_mesh
 from repro.runtime import AdaptiveOptions, TrainOptions, train
 
@@ -62,6 +63,11 @@ def main():
                          "('' = off)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    if not args.reduced and jax.default_backend() == "cpu":
+        raise SystemExit("--full builds the published widths and needs an "
+                         "accelerator; jax found only CPU devices")
+    print(f"compile cache: {enable_compile_cache()}")
+    hw = resolve_hw("auto")
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -95,12 +101,12 @@ def main():
             adaptive = AdaptiveOptions(retune_every=args.retune_every,
                                        ep_size=max(1, args.mesh_model),
                                        dp=max(1, args.mesh_data),
-                                       hw=TPU_V5E, obs=obs)
+                                       hw=hw, obs=obs)
             print("MPipeMoE: online adaptive (n, strategy) "
                   f"(retune_every={args.retune_every})")
         else:
             cfg = resolve(cfg, local_tokens=args.batch * args.seq,
-                          ep_size=args.mesh_model, hw=TPU_V5E)
+                          ep_size=args.mesh_model, hw=hw)
             print(f"MPipeMoE: n={cfg.moe.num_partitions} "
                   f"strategy={cfg.moe.memory_reuse_strategy}")
 
@@ -127,13 +133,11 @@ def main():
                   f"t={metrics['step_time_s']*1e3:.0f}ms{extra}",
                   flush=True)
 
-    ctx = set_mesh(mesh) if mesh is not None else _null()
     try:
-        with ctx:
-            state, hist = train(cfg, steps=args.steps, batch_source=ds,
-                                opts=opts, dist=dist, checkpointer=ck,
-                                ckpt_every=args.ckpt_every,
-                                heartbeat=heartbeat, adaptive=adaptive)
+        state, hist = train(cfg, steps=args.steps, batch_source=ds,
+                            opts=opts, dist=dist, checkpointer=ck,
+                            ckpt_every=args.ckpt_every,
+                            heartbeat=heartbeat, adaptive=adaptive)
     finally:
         if server is not None:
             server.stop()
@@ -144,14 +148,6 @@ def main():
     if "n" in hist[-1]:                   # controller engaged (MoE arch)
         print(f"adaptive: n={hist[-1]['n']} "
               f"strategy={hist[-1]['strategy']}")
-
-
-class _null:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False
 
 
 if __name__ == "__main__":

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.core.pipeline_moe import pipelined_moe
 from repro.models.module import axes_of
@@ -92,7 +91,7 @@ def apply(params, x, *, cfg: ArchConfig, dist=None, mode: str = "train",
     # sequence axis sharded over the EP axis miscompiles the recurrent
     # mixers on jax 0.4.x (the causal conv / chunked SSM scan partition
     # without the needed halo exchange — wrong *values*, not just a bad
-    # layout; see tests/test_serving_conformance.py's jamba arch leg).
+    # layout; see tests/test_conformance_arch.py's jamba leg).
     # The explicit replicated constraint keeps the residual stream's
     # layout at the boundary and reshards only inside it.
     if seq_shardable:
@@ -115,7 +114,7 @@ def apply(params, x, *, cfg: ArchConfig, dist=None, mode: str = "train",
                 lambda v: jax.lax.pmean(v, reduce_axes), aux)
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh, in_specs=(p_specs, x_spec),
         out_specs=(x_spec, P()))(params, x)
     if seq_shardable:

@@ -6,10 +6,18 @@ Step semantics:
 * MoE aux losses folded into the objective by the model's loss_fn.
 
 Fault tolerance (DESIGN §8): the loop checkpoints every
-``ckpt_every`` steps (async, atomic), retries a failed step
-(``max_retries``), restores from the latest checkpoint on unrecoverable
-errors, and emits heartbeats a cluster monitor can watch for stragglers.
-The data pipeline is seekable, so restart resumes at the exact batch.
+``ckpt_every`` steps (async, atomic), rolls a failed step back to the
+latest checkpoint (at most ``max_retries`` times in a row — the live
+state was donated to the failed step, so it is never re-used), raises
+the step's own error when there is no checkpoint, and emits heartbeats
+a cluster monitor can watch for stragglers. The data pipeline is
+seekable, so restart resumes at the exact batch.
+
+Placement: with a ``dist`` mesh, parameters and optimizer state are
+created already sharded (``state_shardings``: the logical-axis rules of
+``distributed.sharding`` — experts over the expert axis, FSDP over the
+data axes) and every step is jitted with those shardings in and out,
+so no device ever holds the unsharded state.
 
 Online adaptation (paper §III-C Algorithm 1 + §III-E): when the config
 leaves ``num_partitions == 0`` or ``memory_reuse_strategy ==
@@ -22,6 +30,7 @@ compiled-step cache and are free.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import time
@@ -34,9 +43,10 @@ from repro.configs.base import ArchConfig
 from repro.core.selector import Resolver
 from repro.core.types import TPU_V5E, HardwareSpec, Strategy
 from repro.obs import Recorder
+from repro.distributed import sharding as shd
 from repro.distributed.compression import compress_with_feedback
 from repro.models.api import get_model
-from repro.optim import get_optimizer, lr_schedule
+from repro.optim import get_optimizer, lr_schedule, state_shardings
 
 log = logging.getLogger("repro.runtime")
 
@@ -51,16 +61,59 @@ class TrainOptions:
     use_kernel: bool = False
 
 
-def init_state(cfg: ArchConfig, key, opts: TrainOptions) -> Dict[str, Any]:
+def init_state(cfg: ArchConfig, key, opts: TrainOptions,
+               shardings=None) -> Dict[str, Any]:
+    """Fresh train state. ``shardings`` (a :func:`train_state_shardings`
+    tree) builds every leaf directly in its sharded placement."""
+    def build(key):
+        model = get_model(cfg)
+        params = model.init(cfg, key)
+        opt_mod, ocfg = get_optimizer(cfg.optimizer, opts.lr)
+        state = {"params": params, "opt": opt_mod.init(params, ocfg),
+                 "step": jnp.zeros((), jnp.int32)}
+        if opts.compress_grads:
+            state["grad_err"] = jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        return state
+    if shardings is None:
+        return build(key)
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
+def train_state_shardings(cfg: ArchConfig, opts: TrainOptions, mesh
+                          ) -> Dict[str, Any]:
+    """NamedSharding tree of the train state on ``mesh``: parameters by
+    the "train" logical-axis rules, optimizer moments wherever their
+    parameter shard lives, the step counter replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
     model = get_model(cfg)
-    params = model.init(cfg, key)
+    pshard = shd.param_shardings(cfg, shd.make_rules(mesh, "train"), model)
     opt_mod, ocfg = get_optimizer(cfg.optimizer, opts.lr)
-    state = {"params": params, "opt": opt_mod.init(params, ocfg),
-             "step": jnp.zeros((), jnp.int32)}
+    out = {"params": pshard,
+           "opt": state_shardings(opt_mod, ocfg, model.abstract_params(cfg),
+                                  pshard, mesh),
+           "step": NamedSharding(mesh, P())}
     if opts.compress_grads:
-        state["grad_err"] = jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    return state
+        out["grad_err"] = pshard
+    return out
+
+
+def _batch_shardings(mesh, batch) -> Dict[str, Any]:
+    """Batch dim over the data axes (when it divides), the rest whole."""
+    rules = shd.make_rules(mesh, "train")
+    return {k: rules.sharding_for(v.shape,
+                                  ("batch",) + (None,) * (v.ndim - 1), k)
+            for k, v in batch.items()}
+
+
+def _jit_step(fn, shardings, donate: bool):
+    """jit a train step; with ``shardings`` the state enters and leaves
+    in its sharded placement (metrics are replicated scalars)."""
+    kw = {"donate_argnums": (0,)} if donate else {}
+    if shardings is not None:
+        kw.update(in_shardings=(shardings, None),
+                  out_shardings=(shardings, None))
+    return jax.jit(fn, **kw)
 
 
 def abstract_state(cfg: ArchConfig, opts: TrainOptions) -> Dict[str, Any]:
@@ -185,6 +238,10 @@ class AdaptiveController:
         self.opts = opts
         self.dist = dist
         self.jit = jit
+        # (n, strategy) never changes the state's shapes, so one
+        # placement serves every candidate step
+        self.shardings = (train_state_shardings(cfg, opts, dist.mesh)
+                          if dist is not None else None)
         self.aopts = aopts or AdaptiveOptions()
         if dist is not None:
             # derive the EP/DP extents from the live mesh unless the
@@ -267,8 +324,8 @@ class AdaptiveController:
         if fn is None:
             fn = make_train_step(self._cfg_with(n, strategy.value),
                                  self.opts, self.dist)
-            if self.jit:
-                fn = jax.jit(fn)         # no donation: state is reused
+            if self.jit:                 # no donation: state is reused
+                fn = _jit_step(fn, self.shardings, donate=False)
             self._cache_put(self._measure_cache, key, fn)
         out = fn(state, batch)
         jax.block_until_ready(out)       # compile + warm up
@@ -317,7 +374,7 @@ class AdaptiveController:
             fn = make_train_step(self._cfg_with(n, strategy), self.opts,
                                  self.dist)
             if self.jit:
-                fn = jax.jit(fn, donate_argnums=(0,))
+                fn = _jit_step(fn, self.shardings, donate=True)
             self._cache_put(self._step_cache, key, fn)
             self.rejit_count += 1
         info.update(n=n, strategy=strategy)
@@ -342,6 +399,9 @@ def train(cfg: ArchConfig, *, steps: int, batch_source,
     "adaptive"``); pass ``False`` to force the static path, an
     :class:`AdaptiveOptions` to tune it, or a pre-built
     :class:`AdaptiveController` (benchmarks/tests inspect its counters).
+    With ``dist`` (and ``jit``) the state is created and kept sharded
+    over ``dist.mesh`` (:func:`train_state_shardings`) and the loop runs
+    under that mesh.
     Returns (final_state, history list of metric dicts).
     """
     opts = opts or TrainOptions()
@@ -357,27 +417,38 @@ def train(cfg: ArchConfig, *, steps: int, batch_source,
         aopts = adaptive if isinstance(adaptive, AdaptiveOptions) else None
         controller = AdaptiveController(cfg, opts, dist, aopts, jit=jit)
 
-    if state is None:
-        state = init_state(cfg, jax.random.PRNGKey(0), opts)
-    start = 0
-    if checkpointer is not None:
-        restored = checkpointer.restore_latest(abstract=None)
-        if restored is not None:
-            state, start = restored["state"], int(restored["step"])
-            log.info("restored checkpoint at step %d", start)
+    shardings = None
+    if dist is not None and jit:
+        shardings = (controller.shardings if controller is not None
+                     else train_state_shardings(cfg, opts, dist.mesh))
+    mesh_scope = (jax.set_mesh(dist.mesh) if dist is not None
+                  else contextlib.nullcontext())
+    with mesh_scope:
+        if state is None:
+            state = init_state(cfg, jax.random.PRNGKey(0), opts, shardings)
+        start = 0
+        if checkpointer is not None:
+            restored = checkpointer.restore_latest(abstract=None)
+            if restored is not None:
+                state, start = restored["state"], int(restored["step"])
+                if shardings is not None:
+                    state = jax.device_put(state, shardings)
+                log.info("restored checkpoint at step %d", start)
 
-    if controller is None:
-        step_fn = make_train_step(cfg, opts, dist)
-        if jit:
-            step_fn = jax.jit(step_fn, donate_argnums=(0,))
+        if controller is None:
+            step_fn = make_train_step(cfg, opts, dist)
+            if jit:
+                step_fn = _jit_step(step_fn, shardings, donate=True)
 
-    history = []
-    step = start
-    while step < steps:
-        batch = {k: jnp.asarray(v)
-                 for k, v in batch_source.batch_at(step).items()}
-        attempt = 0
-        while True:
+        history = []
+        step = start
+        failures = 0                       # consecutive failed steps
+        while step < steps:
+            batch = {k: jnp.asarray(v)
+                     for k, v in batch_source.batch_at(step).items()}
+            if shardings is not None:
+                batch = jax.device_put(batch, _batch_shardings(dist.mesh,
+                                                               batch))
             try:
                 ainfo = {}
                 if controller is not None:
@@ -387,26 +458,28 @@ def train(cfg: ArchConfig, *, steps: int, batch_source,
                 metrics = {k: float(v) for k, v in metrics.items()}
                 metrics["step_time_s"] = time.perf_counter() - t0
                 metrics.update(ainfo)
-                break
-            except Exception:                      # pragma: no cover
-                attempt += 1
-                log.exception("step %d failed (attempt %d)", step, attempt)
-                if attempt > max_retries:
-                    if checkpointer is not None:
-                        restored = checkpointer.restore_latest(abstract=None)
-                        if restored is not None:
-                            state = restored["state"]
-                            step = int(restored["step"])
-                            log.warning("rolled back to step %d", step)
-                            attempt = 0
-                            continue
+            except Exception:
+                # the failed call may have consumed the donated state,
+                # so the only safe retry starts from a checkpoint;
+                # without one the step's own error is the one to raise
+                failures += 1
+                log.exception("step %d failed (%d in a row)", step, failures)
+                restored = (checkpointer.restore_latest(abstract=None)
+                            if checkpointer is not None else None)
+                if restored is None or failures > max_retries:
                     raise
-        history.append({"step": step, **metrics})
-        if heartbeat is not None:
-            heartbeat(step, metrics)
-        step += 1
-        if checkpointer is not None and step % ckpt_every == 0:
-            checkpointer.save(step, state)
-    if checkpointer is not None:
-        checkpointer.save(steps, state, block=True)
-    return state, history
+                state, step = restored["state"], int(restored["step"])
+                if shardings is not None:
+                    state = jax.device_put(state, shardings)
+                log.warning("rolled back to step %d", step)
+                continue
+            failures = 0
+            history.append({"step": step, **metrics})
+            if heartbeat is not None:
+                heartbeat(step, metrics)
+            step += 1
+            if checkpointer is not None and step % ckpt_every == 0:
+                checkpointer.save(step, state)
+        if checkpointer is not None:
+            checkpointer.save(steps, state, block=True)
+        return state, history

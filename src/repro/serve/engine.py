@@ -42,13 +42,13 @@ same LRU the serving steps use — the winner's program is already warm.
 
 Mesh-sharded serving (``EngineOptions.devices > 1``): the engine builds
 a ``(data=dp, model=ep)`` mesh through
-``distributed.context.make_serving_context`` (all mesh calls via the
-``repro.compat`` shims), shards the expert weights over the EP axis and
-replicates everything else, and threads the resulting ``DistContext``
-into both jitted step bodies. Chunked prefill then runs
-``pipelined_moe``'s **sharded** layout (tokens split over EP, real
-dispatch/combine All-to-Alls — which the wall-clock measure therefore
-times too) while decode runs the **replicated** psum-combine layout.
+``distributed.context.make_serving_context``, shards the expert
+weights over the EP axis and replicates everything else, and threads
+the resulting ``DistContext`` into both jitted step bodies. Chunked
+prefill then runs ``pipelined_moe``'s **sharded** layout (tokens split
+over EP, real dispatch/combine All-to-Alls — which the wall-clock
+measure therefore times too) while decode runs the **replicated**
+psum-combine layout.
 
 The cache pools have two mesh layouts
 (``EngineOptions.kv_sharding``, see ``repro.serve.state_cache``):
@@ -77,7 +77,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.configs.base import ArchConfig
 from repro.core.memory_model import PreemptionCost
 from repro.core.strategies import host_offload_supported
@@ -265,6 +264,7 @@ class Engine:
         self.decode_traces = 0
         self.prefill_traces = 0
         self.preempts: Dict[str, int] = {"recompute": 0, "offload": 0}
+        self.nonfinite_logit_rows = 0
         # high-water mark of concurrently running requests while the
         # engine had not yet preempted anyone — the "admitted before
         # first preemption" capacity the DP-sharded benchmark reports
@@ -300,6 +300,9 @@ class Engine:
             "XLA traces of the jitted step bodies", ["body"])
         self._m_compiles = reg.counter(
             "repro_prefill_compiles_total", "compiled prefill programs")
+        self._m_nonfinite = reg.counter(
+            "repro_nonfinite_logit_rows_total",
+            "served logit rows holding a NaN or inf")
         self._m_step_s = reg.histogram(
             "repro_step_seconds", "host wall time per engine step",
             ["kind"])
@@ -369,11 +372,11 @@ class Engine:
 
     def _mesh_scope(self):
         """Context activating the mesh around traces/executions (the
-        jax-0.4.x resource env that bare-PartitionSpec constraints in
-        ``DistContext.constrain`` need)."""
+        bare-PartitionSpec constraints in ``DistContext.constrain``
+        resolve against it)."""
         if self.dist is None:
             return contextlib.nullcontext()
-        return set_mesh(self.dist.mesh)
+        return jax.set_mesh(self.dist.mesh)
 
     def _pin_pools(self, pools):
         """Keep step outputs on the committed pool layout (replicated,
@@ -398,8 +401,9 @@ class Engine:
             attn_kernel=self._attn_kernel,
             kv_sharded=(self.opts.kv_sharding == "dp"
                         and self.kv.n_shards > 1))
-        return sample_tokens(logits, temp, top_k, top_p, seed, pos), \
-            self._pin_pools(new_pools)
+        return (sample_tokens(logits, temp, top_k, top_p, seed, pos),
+                jnp.isfinite(logits).all(axis=-1),
+                self._pin_pools(new_pools))
 
     def _prefill_fn(self, bucket: int, rcfg: ArchConfig) -> Callable:
         m = rcfg.moe
@@ -416,8 +420,10 @@ class Engine:
                 logits, new_pools = self.model.prefill_chunk_paged(
                     params, pools, pt_row, pos0, toks, valid_len, _cfg,
                     dist=self.dist, write_sink=sink, slot=slot)
-                return sample_tokens(logits, temp, top_k, top_p, seed,
-                                     pos), self._pin_pools(new_pools)
+                return (sample_tokens(logits, temp, top_k, top_p, seed,
+                                      pos),
+                        jnp.isfinite(logits).all(axis=-1),
+                        self._pin_pools(new_pools))
             fn = jax.jit(body)
             self.prefill_rejits += 1
             self._m_compiles.inc()
@@ -548,13 +554,7 @@ class Engine:
         kv = self.kv
         before = self.prefill_rejits
         with self._mesh_scope():
-            out = self._decode_fn(
-                self.params, kv.pools,
-                kv.device_page_table(), kv.device_lens(),
-                self._put_slots(np.zeros((kv.max_slots, 1), np.int32)),
-                self._put_slots(np.zeros((kv.max_slots,), bool)),
-                self._decode_sinks,
-                *self._sample_args([None] * kv.max_slots, slots=True))
+            out = self._decode_fn(*self._idle_decode_args())
             jax.block_until_ready(out[0])
         buckets, c = set(), 1
         while c < self.scheduler.chunk:
@@ -573,6 +573,24 @@ class Engine:
                          *self._sample_args([None]))
                 jax.block_until_ready(out[0])
         return 1 + self.prefill_rejits - before
+
+    def _idle_decode_args(self) -> tuple:
+        """Decode-step arguments with every slot inactive (all KV writes
+        masked into the sink page) — the warmup/lowering probe."""
+        kv = self.kv
+        return (self.params, kv.pools, kv.device_page_table(),
+                kv.device_lens(),
+                self._put_slots(np.zeros((kv.max_slots, 1), np.int32)),
+                self._put_slots(np.zeros((kv.max_slots,), bool)),
+                self._decode_sinks,
+                *self._sample_args([None] * kv.max_slots, slots=True))
+
+    def compiled_decode_text(self) -> str:
+        """HLO text of the compiled decode program. Lowering re-traces
+        the decode body, so ``decode_traces`` counts one more trace."""
+        with self._mesh_scope():
+            return self._decode_fn.lower(
+                *self._idle_decode_args()).compile().as_text()
 
     # -- preemption ------------------------------------------------------
     def _pick_victim(self, shard: Optional[int] = None
@@ -720,13 +738,13 @@ class Engine:
                          args={"chunk": c, "bucket": bucket,
                                "pos": req.prefill_pos}), \
              self._mesh_scope():
-            tok, kv.pools = fn(self.params, kv.pools,
-                               kv.device_page_table(slot),
-                               kv.device_lens(slot), self._put(toks),
-                               self._put(np.asarray(c, np.int32)),
-                               self._put(np.asarray([slot], np.int32)),
-                               self._put(kv.sink_row(slot)),
-                               *self._sample_args([req]))
+            tok, finite, kv.pools = fn(
+                self.params, kv.pools, kv.device_page_table(slot),
+                kv.device_lens(slot), self._put(toks),
+                self._put(np.asarray(c, np.int32)),
+                self._put(np.asarray([slot], np.int32)),
+                self._put(kv.sink_row(slot)), *self._sample_args([req]))
+        self._count_nonfinite(np.asarray(finite))
         req.prefill_pos += c
         kv.lens[slot] += c
         self._m_prefill_tokens.inc(c)
@@ -774,12 +792,13 @@ class Engine:
         with self.obs.tracer.span("decode",
                                   args={"slots": len(slots)}), \
              self._mesh_scope():
-            toks, kv.pools = self._decode_fn(
+            toks, finite, kv.pools = self._decode_fn(
                 self.params, kv.pools, kv.device_page_table(),
                 kv.device_lens(), self._put_slots(tokens),
                 self._put_slots(active), self._decode_sinks,
                 *self._sample_args(by_slot, slots=True))
         toks = np.asarray(toks)
+        self._count_nonfinite(np.asarray(finite)[slots])
         now = time.perf_counter()
         for s in slots:
             req = self.scheduler.running[s]
@@ -787,6 +806,14 @@ class Engine:
             if req.emit(int(toks[s]), now):
                 self._retire(req)
         return {"tokens": len(slots)}
+
+    def _count_nonfinite(self, finite: np.ndarray) -> None:
+        """Served logit rows with a NaN/inf entry: a sampled token from
+        such a row is garbage, so the count is a model-health signal."""
+        bad = int(finite.size - np.count_nonzero(finite))
+        if bad:
+            self.nonfinite_logit_rows += bad
+            self._m_nonfinite.inc(bad)
 
     def _retire(self, req: Request) -> None:
         # publish the retiring request's written full pages (prompt plus
@@ -843,6 +870,7 @@ class Engine:
             "prefill_compiles": self.prefill_rejits,
             "decode_traces": self.decode_traces,
             "prefill_traces": self.prefill_traces,
+            "nonfinite_logit_rows": self.nonfinite_logit_rows,
             "p50_latency_s": quantile(lat, 50),
             "p99_latency_s": quantile(lat, 99),
             "p50_ttft_s": quantile(ttft, 50),

@@ -7,6 +7,7 @@ seed, so benchmark runs are reproducible.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List
 
 import numpy as np
@@ -15,11 +16,22 @@ __all__ = ["TraceEntry", "dense_greedy_reference", "poisson_trace",
            "replay", "run_poisson"]
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted_decode_step():
+    import jax
+
+    from repro.models import lm
+
+    return jax.jit(lm.decode_step, static_argnums=3)
+
+
 def dense_greedy_reference(params, cfg, prompt, max_new: int):
     """Golden reference: dense-cache sequential prefill + greedy decode,
     one request at a time (the legacy serve loop). The token-exactness
     oracle the paged / continuously-batched / preemptive engine is
-    checked against in tests and the overload benchmark."""
+    checked against in tests and the overload benchmark. The decode
+    step is jitted (one compile per cache length), the prefill runs
+    once per request op by op."""
     import jax.numpy as jnp
 
     from repro.models import lm
@@ -29,9 +41,10 @@ def dense_greedy_reference(params, cfg, prompt, max_new: int):
                                max_len=len(prompt) + max_new,
                                dtype=jnp.float32)
     out = [int(np.argmax(np.asarray(logits[0, -1])))]
+    step = _jitted_decode_step()
     for _ in range(max_new - 1):
-        lg, cache = lm.decode_step(
-            params, cache, np.asarray([[out[-1]]], np.int32), cfg)
+        lg, cache = step(params, cache,
+                         np.asarray([[out[-1]]], np.int32), cfg)
         out.append(int(np.argmax(np.asarray(lg[0]))))
     return out
 
@@ -64,7 +77,7 @@ def poisson_trace(num_requests: int, *, rate: float, vocab_size: int,
 def run_poisson(cfg, options, *, requests: int, rate: float,
                 prompt_max: int, gen_max: int, seed: int = 0,
                 eos_id=None, time_scale: float = 1.0, sampling=None,
-                params=None, on_engine=None):
+                params=None, on_engine=None, gen_min: int = 2):
     """Build an Engine for ``cfg``/``options``, replay a Poisson trace
     through it, and return ``(engine, wall_s)`` — the shared body of the
     serving CLI and ``benchmarks/serving.py``. ``sampling`` (a
@@ -72,7 +85,8 @@ def run_poisson(cfg, options, *, requests: int, rate: float,
     request; ``params`` reuses an existing parameter tree (so two engines
     can be compared on identical weights); ``on_engine(engine)`` runs
     after warmup but before the replay — the hook the CLI uses to attach
-    the live ``/metrics`` exporter to the engine's gauge refresher."""
+    the live ``/metrics`` exporter to the engine's gauge refresher.
+    Generation budgets are drawn from ``[gen_min, gen_max]``."""
     import time
 
     from repro.serve.engine import Engine
@@ -83,7 +97,7 @@ def run_poisson(cfg, options, *, requests: int, rate: float,
         on_engine(engine)
     trace = poisson_trace(requests, rate=rate, vocab_size=cfg.vocab_size,
                           prompt_len_range=(4, prompt_max),
-                          gen_len_range=(2, gen_max), seed=seed)
+                          gen_len_range=(gen_min, gen_max), seed=seed)
     t0 = time.perf_counter()
     replay(engine, trace, eos_id=eos_id, time_scale=time_scale,
            sampling=sampling)

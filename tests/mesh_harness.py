@@ -17,8 +17,9 @@ results back over a one-line JSON protocol:
 
 Used by ``tests/test_distributed.py`` (training-side EP/decode
 parity), ``tests/test_serving_sharded.py`` (mesh-sharded serving
-token-exactness) and ``tests/test_serving_conformance.py`` (the
-serving conformance matrix + jit-compile-count regression).
+token-exactness) and the serving conformance tiers
+(``tests/test_serving_conformance.py``, ``tests/test_conformance_*.py``:
+the conformance matrix + jit-compile-count regression).
 """
 from __future__ import annotations
 

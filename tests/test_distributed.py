@@ -23,11 +23,11 @@ params = lm.init(cfg, key)
 loss_ref, _ = lm.loss_fn(params, batch, cfg)
 g_ref = jax.grad(lambda p: lm.loss_fn(p, batch, cfg)[0])(params)
 
-from repro.compat import make_mesh, set_mesh
+from repro.compat import make_mesh
 mesh = make_mesh((2, 4), ('data', 'model'))
 dist = DistContext(mesh=mesh, dp_axes=('data',), ep_axis='model',
                    tp_axis='model')
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     loss_d = jax.jit(lambda p, b: lm.loss_fn(p, b, cfg, dist=dist)[0]
                      )(params, batch)
     g_d = jax.jit(jax.grad(lambda p, b: lm.loss_fn(p, b, cfg,
@@ -57,11 +57,11 @@ tok = jax.random.randint(key, (B, 1), 0, cfg.vocab_size)
 cache0 = lm.init_cache(cfg, B, max_len=32, dtype=jnp.float32)
 ref, _ = lm.decode_step(params, cache0, tok, cfg)
 
-from repro.compat import make_mesh, set_mesh
+from repro.compat import make_mesh
 mesh = make_mesh((2, 4), ('data', 'model'))
 dist = DistContext(mesh=mesh, dp_axes=('data',), ep_axis='model',
                    tp_axis='model')
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     cache1 = lm.init_cache(cfg, B, max_len=32, dtype=jnp.float32)
     out, _ = jax.jit(lambda p, c, t: lm.decode_step(p, c, t, cfg,
                                                     dist=dist)
@@ -82,6 +82,55 @@ def test_ep_shard_map_matches_single_device():
 def test_moe_decode_replicated_path_matches():
     res = run_mesh_script(_DECODE_SCRIPT)
     assert res["decode_diff"] < 1e-3
+
+
+# expert-parallel training as chip_smoke.py --chips 4 runs it: a
+# data=1 x model=4 mesh; train() must create the state already sharded
+# (experts split over "model") and keep it so across donated steps, and
+# step 0's loss must match the unsharded single-device step
+_TRAIN_EP_SCRIPT = r"""
+import dataclasses, json
+import jax, numpy as np
+from repro.configs import get_config
+from repro.data import SyntheticTokens
+from repro.distributed.context import DistContext
+from repro.launch.mesh import make_host_mesh
+from repro.runtime import TrainOptions, train
+
+base = get_config('moe-gpt3-s').reduced()
+cfg = dataclasses.replace(base, compute_dtype='float32',
+                          moe=dataclasses.replace(
+                              base.moe, num_experts=8, num_partitions=2,
+                              memory_reuse_strategy='s4'))
+ds = SyntheticTokens(cfg, batch=4, seq=32, seed=0)
+opts = TrainOptions(lr=1e-3, warmup=1, total_steps=2)
+mesh = make_host_mesh(1, 4)
+dist = DistContext(mesh=mesh, dp_axes=('data',), ep_axis='model',
+                   tp_axis='model')
+state, hist = train(cfg, steps=2, batch_source=ds, opts=opts, dist=dist,
+                    adaptive=False)
+_, ref = train(cfg, steps=1, batch_source=ds, opts=opts, adaptive=False)
+w = state['params']['periods']['l1']['moe']['experts']['w_up']
+m = state['opt']['m']['periods']['l1']['moe']['experts']['w_up']
+print(json.dumps({
+    'w_spec': [str(a) for a in w.sharding.spec],
+    'w_shard_experts': sorted({s.data.shape[1]
+                               for s in w.addressable_shards}),
+    'm_shard_experts': sorted({s.data.shape[1]
+                               for s in m.addressable_shards}),
+    'n_devices': len(w.sharding.device_set),
+    'loss_diff': abs(hist[0]['loss'] - ref[0]['loss']),
+}))
+"""
+
+
+def test_train_places_expert_parallel_state_sharded():
+    res = run_mesh_script(_TRAIN_EP_SCRIPT, devices=4)
+    assert res["n_devices"] == 4
+    assert res["w_spec"][1] == "model"            # [periods, experts, ...]
+    assert res["w_shard_experts"] == [2]          # 8 experts / 4 chips
+    assert res["m_shard_experts"] == [2]          # Adam moments follow
+    assert res["loss_diff"] < 1e-3
 
 
 def test_sharding_rules_divisibility_fallback():

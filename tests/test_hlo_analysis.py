@@ -35,11 +35,11 @@ def test_collective_parser_on_psum():
     def f(x):
         return jax.lax.psum(x, "i")
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, set_mesh, shard_map
+    from repro.compat import make_mesh
     mesh = make_mesh((1,), ("i",))
-    with set_mesh(mesh):
-        g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("i"),
-                              out_specs=P()))
+    with jax.set_mesh(mesh):
+        g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("i"),
+                                  out_specs=P()))
         compiled = g.lower(
             jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
     coll = H.collective_bytes(compiled.as_text())

@@ -279,3 +279,14 @@ def test_resolve_hw():
     assert resolve_hw("auto") is CPU_HOST
     with pytest.raises(KeyError):
         resolve_hw("abacus-9000")
+
+
+def test_resolve_hw_keys_on_device_kind():
+    """auto detection reads ``device_kind``: a v5e reports "TPU v5 lite";
+    a kind with no spec is refused rather than planned as a v5e."""
+    from repro.core.types import hw_for_device_kind
+    assert hw_for_device_kind("TPU v5 lite") is TPU_V5E
+    assert hw_for_device_kind("cpu") is CPU_HOST
+    for kind in ("TPU v4", "TPU v6 lite", "abacus"):
+        with pytest.raises(ValueError, match="no HardwareSpec"):
+            hw_for_device_kind(kind)

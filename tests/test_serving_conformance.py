@@ -18,30 +18,10 @@ blocking admission over an ample pool. Multi-device combos run through
 all three preempt modes (amortizing jax init + golden refs) and the
 parametrized tests assert their slice.
 
-The **arch axis** extends the matrix over the StateCache kinds: plain
-attention (h2o-danube, sliding window), pure mamba, pure xLSTM, MLA
-latent paging (deepseek-v2-lite) and the jamba attn+mamba composite —
-each × every preempt mode on the 8-device mesh, with preemption storms
-*forced* via ``EngineOptions.storm_every`` (a constant-state cache
-holds O(1) bytes per slot and never runs dry on its own; forcing makes
-the storm legs uniform across kinds while the moe-gpt3-s matrix above
-keeps pinning the organic pool-dry path). Drain checks are protocol-
-level (``used_bytes == 0`` / ``free_units`` full / nothing parked) so
-they hold for every cache kind.
-
-The **attn_kernel axis** runs the moe-gpt3-s storm legs twice per
-``kv_sharding`` — once on the legacy ``gather_pages`` + dense-attention
-baseline, once on the fused Pallas paged-decode kernel
-(``EngineOptions.attn_kernel``) — and pins them token-exact against the
-dense golden loop *and* jit-counter-identical against each other: the
-kernel selection is trace-static, so switching it may not add a single
-trace or compile. The dp leg additionally lowers the compiled decode
-program and asserts the Pallas HLO contains **no all-gather of the page
-pool and zero wide (rank >= 4) f32 collectives** — the cross-shard KV
-traffic XLA emits for the sharded ``gather_pages`` path (masked gather
-+ rank-5 f32 all-reduce per attention layer) must be gone, because the
-kernel reads pages shard-locally under ``shard_map``. The gather leg is
-asserted to still carry that traffic, so the assertion keeps teeth.
+The arch, attn_kernel and prefix_cache axes of the matrix live in
+``test_conformance_arch.py``, ``test_conformance_attn_kernel.py`` and
+``test_conformance_prefix_cache.py``; the setup they share is in
+``conformance_harness.py``.
 
 The compile-count regression pins the PR 4 one-committed-placement
 gotcha under the DP-KV layout: every step input must enter jit with one
@@ -51,95 +31,20 @@ pool layout (``pin_pools``) — otherwise the jit caches churn on
 sharding mismatches. Steady state must compile the decode body exactly
 once and each reachable prefill bucket exactly once, counted by the
 engine's own trace counters (``decode_traces`` / ``prefill_traces`` —
-the jitted bodies increment them only while tracing). The arch axis
-asserts the same counters, so the invariant holds for recurrent state
-threading (slot-sliced prefill writes, frozen inactive decode slots)
-too. A **telemetry leg** replays the recompute storm with the
-``repro.obs`` span tracer live and must match the telemetry-off leg's
-jit counters exactly — instrumentation runs at trace time only, so
-telemetry on/off cannot change compiled HLO (see
-``docs/observability.md``).
+the jitted bodies increment them only while tracing). A **telemetry
+leg** replays the recompute storm with the ``repro.obs`` span tracer
+live and must match the telemetry-off leg's jit counters exactly —
+instrumentation runs at trace time only, so telemetry on/off cannot
+change compiled HLO (see ``docs/observability.md``).
 """
 import pytest
 
+from conformance_harness import (COMMON, DEVICES, GOLDEN_SETUP,
+                                 KV_SHARDINGS, LENS, MAX_NEW, PREEMPTS,
+                                 STORM_PAGES, check_combo)
 from mesh_harness import run_mesh_script
 
-PREEMPTS = ("never", "recompute", "offload")
-DEVICES = (1, 8)
-KV_SHARDINGS = ("replicated", "dp")
-
-# decode-heavy budgets (10..14 pages at page_size 4) over a 30-page pool
-# (replicated: 29 usable; dp=2: 14 usable per shard): growth overcommits
-# both layouts, so recompute/offload storms fire per shard
-_LENS = (13, 29, 7, 21, 5)
-_MAX_NEW = (26, 24, 28, 25, 27)
-_STORM_PAGES = 30
-
-# the golden setup (model, prompts, dense references) is ONE source
-# block: the subprocess template embeds it and the in-process
-# single-device fixture exec()s the very same string, so the
-# devices=1 and devices=8 legs of the matrix can never drift onto
-# different models or workloads
-_GOLDEN_SETUP = r"""
-import dataclasses
-import jax
-import numpy as np
-from repro.configs import get_config
-from repro.models import lm
-from repro.serve import Engine, EngineOptions, dense_greedy_reference
-
-cfg = get_config('moe-gpt3-s').reduced()
-cfg = dataclasses.replace(
-    cfg, compute_dtype='float32',
-    moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-params = lm.init(cfg, jax.random.PRNGKey(0))
-rng = np.random.Generator(np.random.Philox(key=7))
-lens, max_new = %(lens)r, %(max_new)r
-prompts = [rng.integers(0, cfg.vocab_size, size=n, dtype=np.int32)
-           for n in lens]
-refs = [dense_greedy_reference(params, cfg, p, m)
-        for p, m in zip(prompts, max_new)]
-"""
-
-_COMMON = _GOLDEN_SETUP + r"""
-import json
-
-def run_engine(**over):
-    kw = dict(page_size=4, max_slots=4, max_seq_len=64, chunk=16,
-              min_bucket=8, devices=8, kv_sharding=%(kv)r)
-    kw.update(over)
-    eng = Engine(cfg, params, options=EngineOptions(**kw))
-    eng.warmup()
-    for p, m in zip(prompts, max_new):
-        eng.submit(p, max_new_tokens=m, arrival_s=0.0)
-    eng.run_until_idle()
-    outs = [r.output for r in sorted(eng.done, key=lambda r: r.rid)]
-    return eng, outs
-
-def report(eng, outs):
-    kv, s = eng.kv, eng.stats()
-    return {
-        'token_exact': outs == refs,
-        'preempt_recompute': eng.preempts['recompute'],
-        'preempt_offload': eng.preempts['offload'],
-        'swap_out': s['swap_out_bytes'], 'swap_in': s['swap_in_bytes'],
-        'kv_shards': kv.n_shards,
-        'drained': all(
-            sorted(kv._free_by_shard[sh]) == list(
-                range(sh * kv.pages_per_shard + 1,
-                      (sh + 1) * kv.pages_per_shard))
-            for sh in range(kv.n_shards)),
-        'offloaded_left': kv.offloaded_count,
-        'decode_traces': s['decode_traces'],
-        'prefill_traces': s['prefill_traces'],
-        'prefill_compiles': s['prefill_compiles'],
-        'buckets': len(eng.adaptive.resolutions),
-        'sticky': all(r.kv_shard in range(kv.n_shards)
-                      for r in eng.done),
-    }
-"""
-
-_MATRIX_SCRIPT = _COMMON + r"""
+_MATRIX_SCRIPT = COMMON + r"""
 out = {}
 for mode in ('never', 'recompute', 'offload'):
     eng, outs = run_engine(
@@ -166,9 +71,8 @@ def _matrix(kv_sharding: str) -> dict:
     storm script, hence the raised timeout."""
     if kv_sharding not in _matrix_cache:
         _matrix_cache[kv_sharding] = run_mesh_script(
-            _MATRIX_SCRIPT % {"kv": kv_sharding, "lens": _LENS,
-                              "max_new": _MAX_NEW,
-                              "pages": _STORM_PAGES},
+            _MATRIX_SCRIPT % {"kv": kv_sharding, "lens": LENS,
+                             "max_new": MAX_NEW, "pages": STORM_PAGES},
             timeout=1800)
     return _matrix_cache[kv_sharding]
 
@@ -180,7 +84,7 @@ def single_device_setup():
     """exec() the exact setup source the subprocess template embeds —
     one block, two legs, zero drift."""
     ns: dict = {}
-    exec(_GOLDEN_SETUP % {"lens": _LENS, "max_new": _MAX_NEW}, ns)
+    exec(GOLDEN_SETUP % {"lens": LENS, "max_new": MAX_NEW}, ns)
     return ns["cfg"], ns["params"], ns["prompts"], ns["refs"]
 
 
@@ -190,8 +94,8 @@ def _run_single(setup, preempt: str) -> dict:
     eng = Engine(cfg, params, options=EngineOptions(
         page_size=4, max_slots=4, max_seq_len=64, chunk=16, min_bucket=8,
         preempt=preempt,
-        num_pages=(0 if preempt == "never" else _STORM_PAGES)))
-    for p, m in zip(prompts, _MAX_NEW):
+        num_pages=(0 if preempt == "never" else STORM_PAGES)))
+    for p, m in zip(prompts, MAX_NEW):
         eng.submit(p, max_new_tokens=m, arrival_s=0.0)
     eng.run_until_idle()
     outs = [r.output for r in sorted(eng.done, key=lambda r: r.rid)]
@@ -209,19 +113,6 @@ def _run_single(setup, preempt: str) -> dict:
 
 # -- the matrix --------------------------------------------------------------
 
-def _check_combo(r: dict, preempt: str) -> None:
-    assert r["token_exact"]
-    assert r["drained"] and r["offloaded_left"] == 0
-    if preempt == "never":
-        assert r["preempt_recompute"] == 0 and r["preempt_offload"] == 0
-    elif preempt == "recompute":
-        assert r["preempt_recompute"] > 0 and r["preempt_offload"] == 0
-        assert r["swap_out"] == 0
-    else:
-        assert r["preempt_offload"] > 0 and r["preempt_recompute"] == 0
-        assert r["swap_out"] > 0 and r["swap_in"] == r["swap_out"]
-
-
 @pytest.mark.parametrize("kv_sharding", KV_SHARDINGS)
 @pytest.mark.parametrize("devices", DEVICES)
 @pytest.mark.parametrize("preempt", PREEMPTS)
@@ -237,7 +128,7 @@ def test_conformance_matrix_token_exact(preempt, devices, kv_sharding,
         r = _run_single(single_device_setup, preempt)
     else:
         r = _matrix(kv_sharding)[preempt]
-    _check_combo(r, preempt)
+    check_combo(r, preempt)
 
 
 def test_matrix_covers_every_defined_combo():
@@ -300,471 +191,3 @@ def test_telemetry_adds_zero_jit_traces(kv_sharding):
               "buckets"):
         assert on[k] == off[k], f"{k}: {on[k]} != {off[k]}"
     assert on["trace_events"] > 0
-
-
-# -- attn_kernel axis: fused Pallas paged decode vs gather baseline ----------
-
-_KERNEL_SCRIPT = _COMMON + r"""
-import re
-
-out = {}
-for kern in ('gather', 'pallas'):
-    leg = {}
-    for mode in ('recompute', 'offload'):
-        eng, outs = run_engine(preempt=mode, num_pages=%(pages)d,
-                               attn_kernel=kern)
-        leg[mode] = report(eng, outs)
-    if %(kv)r == 'dp':
-        # lower the live engine's compiled decode program (same arg
-        # construction as Engine.warmup) and count its collectives —
-        # after the reports, since .lower() re-traces the decode body
-        kvc = eng.kv
-        with eng._mesh_scope():
-            hlo = eng._decode_fn.lower(
-                eng.params, kvc.pools,
-                kvc.device_page_table(), kvc.device_lens(),
-                eng._put_slots(np.zeros((kvc.max_slots, 1), np.int32)),
-                eng._put_slots(np.zeros((kvc.max_slots,), bool)),
-                eng._decode_sinks,
-                *eng._sample_args([None] * kvc.max_slots, slots=True)
-            ).compile().as_text()
-        coll = [l for l in hlo.splitlines()
-                if re.search(r'(all-gather|all-reduce|all-to-all|'
-                             r'collective-permute|reduce-scatter)\(', l)]
-        leg['collectives'] = len(coll)
-        # XLA implements the sharded-pool gather as masked local gather
-        # + a wide f32 all-reduce over the gathered-KV extent (rank 5),
-        # not a literal pool all-gather — count both signatures
-        leg['f32_wide_collectives'] = sum(
-            1 for l in coll if re.search(r'f32\[\d+(,\d+){3,}\]', l))
-        leg['pool_all_gathers'] = sum(
-            1 for l in coll if 'all-gather' in l
-            and ',%%d,' %% kvc.num_pages
-            in l.replace('[', ',').replace(']', ','))
-    out[kern] = leg
-print(json.dumps(out))
-"""
-
-_kernel_cache = {}
-
-
-def _kernel_matrix(kv_sharding: str) -> dict:
-    """One subprocess per kv_sharding runs both attn kernels through
-    both storm modes (4 engine runs + golden refs + one HLO lowering)."""
-    if kv_sharding not in _kernel_cache:
-        _kernel_cache[kv_sharding] = run_mesh_script(
-            _KERNEL_SCRIPT % {"kv": kv_sharding, "lens": _LENS,
-                              "max_new": _MAX_NEW,
-                              "pages": _STORM_PAGES},
-            timeout=1800)
-    return _kernel_cache[kv_sharding]
-
-
-@pytest.mark.parametrize("kv_sharding", KV_SHARDINGS)
-@pytest.mark.parametrize("kern", ("gather", "pallas"))
-@pytest.mark.slow
-def test_attn_kernel_matrix_token_exact(kern, kv_sharding):
-    """Both attention kernels emit exactly the dense golden loop's
-    greedy tokens through recompute AND offload preemption storms, at
-    both KV shardings, and drain their allocators — so the fused kernel
-    is token-for-token interchangeable with the gather baseline."""
-    res = _kernel_matrix(kv_sharding)[kern]
-    for mode in ("recompute", "offload"):
-        _check_combo(res[mode], mode)
-
-
-@pytest.mark.parametrize("kv_sharding", KV_SHARDINGS)
-@pytest.mark.slow
-def test_attn_kernel_compile_counts_pinned(kv_sharding):
-    """attn_kernel is trace-static: per kv_sharding, the Pallas legs'
-    decode/prefill trace and compile counts equal the gather legs'
-    exactly (and decode still compiles once) — selecting the kernel
-    cannot churn the jit caches."""
-    res = _kernel_matrix(kv_sharding)
-    for mode in ("recompute", "offload"):
-        g, p = res["gather"][mode], res["pallas"][mode]
-        for k in ("decode_traces", "prefill_traces",
-                  "prefill_compiles", "buckets"):
-            assert g[k] == p[k], f"{mode}/{k}: {g[k]} != {p[k]}"
-        assert p["decode_traces"] == 1, mode
-
-
-@pytest.mark.slow
-def test_attn_kernel_dp_hlo_shard_local():
-    """The dp-leg decode HLO: the Pallas kernel reads pages shard-local
-    under shard_map, so its program contains no all-gather of the page
-    pool and zero wide (rank >= 4) f32 collectives; the gather leg must
-    still carry that cross-shard KV traffic (teeth — if XLA ever
-    optimizes it away, the baseline changed and this pin should be
-    revisited, not the kernel)."""
-    res = _kernel_matrix("dp")
-    g, p = res["gather"], res["pallas"]
-    assert p["pool_all_gathers"] == 0
-    assert p["f32_wide_collectives"] == 0
-    assert g["f32_wide_collectives"] > 0
-    assert p["collectives"] < g["collectives"]
-
-
-# -- arch axis: every StateCache kind x every preempt mode -------------------
-
-# one leg per cache geometry the StateCache protocol serves:
-#   plain-attn  h2o-danube-1.8b       paged      sliding-window attention
-#   mamba       synthetic pure-mamba  constant   conv window + SSM state
-#   xlstm       xlstm-1.3b            constant   mLSTM matrix + sLSTM cell
-#   mla         deepseek-v2-lite-16b  paged      compressed c_kv latents
-#   jamba       jamba-1.5-large-398b  composite  paged attn + constant mamba
-ARCH_KIND = {
-    "h2o-danube-1.8b": "paged",
-    "pure-mamba": "constant",
-    "xlstm-1.3b": "constant",
-    "deepseek-v2-lite-16b": "paged",
-    "jamba-1.5-large-398b": "composite",
-}
-ARCH_AXIS = tuple(sorted(ARCH_KIND))
-
-_ARCH_LENS = (13, 7, 21)
-_ARCH_MAX_NEW = (10, 12, 9)
-# constant-state slots hold O(1) bytes and never run dry, so the storm
-# legs force preemption on a fixed step cadence instead of starving the
-# pool — uniform across cache kinds (the moe-gpt3-s matrix above keeps
-# the organic pool-dry path pinned)
-_ARCH_STORM_EVERY = 7
-
-_ARCH_SETUP = r"""
-import dataclasses
-import jax
-import numpy as np
-from repro.configs import get_config
-from repro.models import lm
-from repro.serve import Engine, EngineOptions, dense_greedy_reference
-
-def _golden(name):
-    cfg = get_config(name).reduced()
-    moe = cfg.moe
-    if moe is not None:
-        moe = dataclasses.replace(moe, capacity_factor=8.0)
-    return dataclasses.replace(cfg, compute_dtype='float32', moe=moe)
-
-arch = %(arch)r
-if arch == 'pure-mamba':
-    # no registry entry is mixer-pure mamba; synthesize one from jamba's
-    # mamba geometry so the constant-kind path is pinned without an
-    # attention layer anywhere in the stack
-    cfg = dataclasses.replace(_golden('jamba-1.5-large-398b'),
-                              block_pattern=('mamba',), moe=None)
-else:
-    cfg = _golden(arch)
-params = lm.init(cfg, jax.random.PRNGKey(0))
-rng = np.random.Generator(np.random.Philox(key=7))
-lens, max_new = %(lens)r, %(max_new)r
-prompts = [rng.integers(0, cfg.vocab_size, size=n, dtype=np.int32)
-           for n in lens]
-refs = [dense_greedy_reference(params, cfg, p, m)
-        for p, m in zip(prompts, max_new)]
-"""
-
-_ARCH_SCRIPT = _ARCH_SETUP + r"""
-import json
-
-def run_engine(mode):
-    kw = dict(page_size=4, max_slots=2, max_seq_len=64, chunk=16,
-              min_bucket=8, devices=8, kv_sharding=%(kv)r, preempt=mode)
-    if mode != 'never':
-        kw['storm_every'] = %(storm)d
-    eng = Engine(cfg, params, options=EngineOptions(**kw))
-    eng.warmup()
-    full = eng.kv.free_units                 # fresh-cache capacity
-    for p, m in zip(prompts, max_new):
-        eng.submit(p, max_new_tokens=m, arrival_s=0.0)
-    eng.run_until_idle()
-    outs = [r.output for r in sorted(eng.done, key=lambda r: r.rid)]
-    kv, s = eng.kv, eng.stats()
-    return {
-        'cache_kind': eng.cache_kind,
-        'token_exact': outs == refs,
-        'preempt_recompute': eng.preempts['recompute'],
-        'preempt_offload': eng.preempts['offload'],
-        'swap_out': s['swap_out_bytes'], 'swap_in': s['swap_in_bytes'],
-        # protocol-level drain: holds for paged, constant and composite
-        'drained': kv.used_bytes == 0 and kv.free_units == full,
-        'offloaded_left': kv.offloaded_count,
-        'decode_traces': s['decode_traces'],
-        'prefill_traces': s['prefill_traces'],
-        'prefill_compiles': s['prefill_compiles'],
-    }
-
-out = {}
-for mode in ('never', 'recompute', 'offload'):
-    out[mode] = run_engine(mode)
-print(json.dumps(out))
-"""
-
-_arch_cache = {}
-
-
-def _arch_matrix(arch: str, kv_sharding: str = "replicated") -> dict:
-    """One subprocess per (arch, kv_sharding) computes all preempt
-    modes, amortizing jax init + model init + golden refs."""
-    key = (arch, kv_sharding)
-    if key not in _arch_cache:
-        _arch_cache[key] = run_mesh_script(
-            _ARCH_SCRIPT % {"arch": arch, "kv": kv_sharding,
-                            "lens": _ARCH_LENS, "max_new": _ARCH_MAX_NEW,
-                            "storm": _ARCH_STORM_EVERY},
-            timeout=1800)
-    return _arch_cache[key]
-
-
-@pytest.mark.parametrize("arch", ARCH_AXIS)
-@pytest.mark.parametrize("preempt", PREEMPTS)
-@pytest.mark.slow
-def test_arch_matrix_token_exact(preempt, arch):
-    """Every cache kind x preempt mode on the 8-device mesh: greedy
-    decode is token-exact vs the dense golden loop, through forced
-    recompute/offload preemption storms, and the cache drains back to
-    its fresh capacity (slots, pages and host snapshots all returned)."""
-    r = _arch_matrix(arch)[preempt]
-    assert r["cache_kind"] == ARCH_KIND[arch]
-    _check_combo(r, preempt)
-
-
-def test_arch_axis_covers_every_cache_kind():
-    """The axis spans all three StateCache kinds and the full 5 x 3
-    grid is asserted (no skips on this axis)."""
-    assert sorted(set(ARCH_KIND.values())) == \
-        ["composite", "constant", "paged"]
-    assert len(ARCH_AXIS) * len(PREEMPTS) == 15
-
-
-@pytest.mark.parametrize("arch", ARCH_AXIS)
-@pytest.mark.slow
-def test_arch_steady_state_compiles_once(arch):
-    """Compile-count regression extended across cache kinds: recurrent
-    state threading (slot-sliced prefill writes, frozen inactive decode
-    slots, constant-state dummy page tables) must not add jit cache
-    entries — one decode trace, one trace per prefill bucket, in every
-    preempt mode."""
-    res = _arch_matrix(arch)
-    for mode in PREEMPTS:
-        r = res[mode]
-        assert r["decode_traces"] == 1, \
-            f"{arch}/{mode}: decode compiled {r['decode_traces']}x"
-        assert r["prefill_traces"] == r["prefill_compiles"], \
-            f"{arch}/{mode}"
-
-
-@pytest.mark.slow
-def test_constant_state_dp_sharded_leg():
-    """Slot-sharded constant-state cache over the mesh data axis: xlstm
-    with kv_sharding='dp' (dense model => dp spans all 8 devices) stays
-    token-exact through forced storms, with host snapshots pinned to a
-    sticky shard across offload/restore."""
-    res = _arch_matrix("xlstm-1.3b", "dp")
-    for mode in PREEMPTS:
-        _check_combo(res[mode], mode)
-
-
-# -- attn_kernel x arch: the MLA latent and composite paged paths ------------
-
-_ARCH_KERNEL_SCRIPT = _ARCH_SETUP + r"""
-import json
-
-def run_engine(kern, mode):
-    eng = Engine(cfg, params, options=EngineOptions(
-        page_size=4, max_slots=2, max_seq_len=64, chunk=16,
-        min_bucket=8, devices=8, kv_sharding=%(kv)r, preempt=mode,
-        storm_every=%(storm)d, attn_kernel=kern))
-    eng.warmup()
-    for p, m in zip(prompts, max_new):
-        eng.submit(p, max_new_tokens=m, arrival_s=0.0)
-    eng.run_until_idle()
-    outs = [r.output for r in sorted(eng.done, key=lambda r: r.rid)]
-    s = eng.stats()
-    return outs, {
-        'cache_kind': eng.cache_kind,
-        'token_exact': outs == refs,
-        'preempts': eng.preempts['recompute'] + eng.preempts['offload'],
-        'decode_traces': s['decode_traces'],
-        'prefill_traces': s['prefill_traces'],
-        'prefill_compiles': s['prefill_compiles'],
-    }
-
-out = {}
-for mode in ('recompute', 'offload'):
-    legs = {}
-    for kern in ('gather', 'pallas'):
-        toks, rep = run_engine(kern, mode)
-        legs[kern] = toks
-        out[f'{kern}_{mode}'] = rep
-    out[f'tokens_equal_{mode}'] = legs['gather'] == legs['pallas']
-print(json.dumps(out))
-"""
-
-# deepseek pins the MLA compressed-latent kernel path, jamba the
-# composite (paged attn + constant mamba) path; the shardings are split
-# across them so the arch x kernel axis touches both layouts without
-# doubling the subprocess count (the moe-gpt3-s kernel matrix above
-# already runs the full kernel x kv_sharding x storm cross)
-ARCH_KERNEL_AXIS = (("deepseek-v2-lite-16b", "dp"),
-                    ("jamba-1.5-large-398b", "replicated"))
-
-_arch_kernel_cache = {}
-
-
-def _arch_kernel_matrix(arch: str, kv_sharding: str) -> dict:
-    key = (arch, kv_sharding)
-    if key not in _arch_kernel_cache:
-        _arch_kernel_cache[key] = run_mesh_script(
-            _ARCH_KERNEL_SCRIPT % {"arch": arch, "kv": kv_sharding,
-                                   "lens": _ARCH_LENS,
-                                   "max_new": _ARCH_MAX_NEW,
-                                   "storm": _ARCH_STORM_EVERY},
-            timeout=1800)
-    return _arch_kernel_cache[key]
-
-
-@pytest.mark.parametrize("arch,kv_sharding", ARCH_KERNEL_AXIS)
-@pytest.mark.slow
-def test_attn_kernel_archs_token_exact(arch, kv_sharding):
-    """MLA latent decode (deepseek) and the composite jamba cache run
-    the fused kernel through forced recompute/offload storms on the
-    8-device mesh: both kernels token-exact vs the dense golden loop
-    and bit-identical to each other, with identical jit counters."""
-    res = _arch_kernel_matrix(arch, kv_sharding)
-    for mode in ("recompute", "offload"):
-        assert res[f"tokens_equal_{mode}"], mode
-        for kern in ("gather", "pallas"):
-            r = res[f"{kern}_{mode}"]
-            assert r["cache_kind"] == ARCH_KIND[arch]
-            assert r["token_exact"], f"{kern}/{mode}"
-            assert r["preempts"] > 0, f"{kern}/{mode}: storm never fired"
-        g, p = res[f"gather_{mode}"], res[f"pallas_{mode}"]
-        for k in ("decode_traces", "prefill_traces", "prefill_compiles"):
-            assert g[k] == p[k], f"{mode}/{k}"
-        assert p["decode_traces"] == 1
-
-
-# -- prefix_cache axis: cross-request page sharing vs the off baseline -------
-
-_PREFIX_SCRIPT = _COMMON + r"""
-def run_prefix(**over):
-    kw = dict(page_size=4, max_slots=4, max_seq_len=64, chunk=16,
-              min_bucket=8, devices=8, kv_sharding=%(kv)r,
-              prefix_cache='on')
-    kw.update(over)
-    eng = Engine(cfg, params, options=EngineOptions(**kw))
-    eng.warmup()
-    waves, hits = [], []
-    for wave in range(2):
-        rs = [eng.submit(p, max_new_tokens=m, arrival_s=0.0)
-              for p, m in zip(prompts, max_new)]
-        eng.run_until_idle()
-        waves.append([r.output for r in rs])
-        hits.append(eng.stats()['prefix_hits'])
-    return eng, waves, hits
-
-out = {}
-for mode in ('never', 'recompute', 'offload'):
-    eng, waves, hits = run_prefix(
-        preempt=mode, num_pages=(0 if mode == 'never' else %(pages)d))
-    kv, s = eng.kv, eng.stats()
-    kv.check_integrity()        # raises -> subprocess fails the leg
-    # every trie page must live on the shard of its root: a dp hit can
-    # only ever bind pages of the shard the request was placed on
-    local = True
-    for sh in range(kv.n_shards):
-        stack = [kv._trie_roots[sh]]
-        while stack:
-            node = stack.pop()
-            stack.extend(node.children.values())
-            if node.page >= 0 and kv.shard_of_page(node.page) != sh:
-                local = False
-    out[mode] = {
-        'token_exact': waves[0] == refs and waves[1] == refs,
-        'cold_hits': hits[0], 'warm_hits': hits[1] - hits[0],
-        'hit_tokens': s['prefix_hit_tokens'],
-        'cow_copies': s['prefix_cow_copies'],
-        'preempts': (eng.preempts['recompute']
-                     + eng.preempts['offload']),
-        'kv_shards': kv.n_shards, 'shard_local': local,
-        'decode_traces': s['decode_traces'],
-        'prefill_traces': s['prefill_traces'],
-        'prefill_compiles': s['prefill_compiles'],
-        'buckets': len(eng.adaptive.resolutions),
-    }
-print(json.dumps(out))
-"""
-
-_prefix_cache_results = {}
-
-
-def _prefix_matrix(kv_sharding: str) -> dict:
-    if kv_sharding not in _prefix_cache_results:
-        _prefix_cache_results[kv_sharding] = run_mesh_script(
-            _PREFIX_SCRIPT % {"kv": kv_sharding, "lens": _LENS,
-                              "max_new": _MAX_NEW,
-                              "pages": _STORM_PAGES},
-            timeout=1800)
-    return _prefix_cache_results[kv_sharding]
-
-
-@pytest.mark.parametrize("kv_sharding", KV_SHARDINGS)
-@pytest.mark.parametrize("preempt", PREEMPTS)
-@pytest.mark.slow
-def test_prefix_cache_matrix_token_exact(preempt, kv_sharding):
-    """prefix_cache='on' x kv_sharding x preempt on the 8-device mesh:
-    the standard trace plus a warm resubmission wave stays bit-identical
-    to the dense golden loop (so to the prefix-off legs), the warm wave
-    actually hits the published prefixes, and the allocator passes the
-    full refcount-conservation audit after both waves."""
-    r = _prefix_matrix(kv_sharding)[preempt]
-    assert r["token_exact"]
-    assert r["shard_local"]
-    if preempt == "never":
-        # worst-case pool: nothing evicts and nothing diverges mid-page
-        # (full-reserve hits are page-aligned), so resubmissions hit —
-        # all of them replicated; under dp the cache-aware placement is
-        # a hint, and a request whose prefix shard has no free slot
-        # falls back to the other shard and misses (observed: 4/5)
-        assert r["cow_copies"] == 0
-        floor = len(_LENS) if r["kv_shards"] == 1 else len(_LENS) - 2
-        assert r["warm_hits"] >= floor
-        assert r["hit_tokens"] > 0
-    elif preempt == "recompute":
-        # recompute resumes re-prefill prompts whose prefixes were
-        # published at prefill-end, so the storm itself produces hits
-        assert r["warm_hits"] >= 1
-        assert r["preempts"] > 0
-    else:
-        # offload: restores bypass prefill entirely (no hit path) and
-        # the tight storm pool evicts trie entries as fast as retires
-        # publish them — hits are possible but not guaranteed; the leg
-        # pins exactness + conservation under sharing, not hit rate
-        assert r["preempts"] > 0
-
-
-@pytest.mark.parametrize("kv_sharding", KV_SHARDINGS)
-@pytest.mark.slow
-def test_prefix_cache_jit_counts_match_off_leg(kv_sharding):
-    """Prefix caching must not perturb compiled shapes: jit trace and
-    compile counters on the prefix-on legs equal the prefix-off matrix
-    legs — skipped prefill only shortens chunk loops over the same
-    warmup-swept buckets, it never introduces a new traced body."""
-    on, off = _prefix_matrix(kv_sharding), _matrix(kv_sharding)
-    for mode in PREEMPTS:
-        for k in ("decode_traces", "prefill_traces",
-                  "prefill_compiles", "buckets"):
-            assert on[mode][k] == off[mode][k], \
-                f"{mode}/{k}: {on[mode][k]} != {off[mode][k]}"
-        assert on[mode]["decode_traces"] == 1
-
-
-@pytest.mark.slow
-def test_prefix_cache_dp_hits_stay_shard_local():
-    """dp-sharded pools: the trie is per shard and every hit binds only
-    pages of the request's own shard (cross-shard sharing would read
-    pages a device does not hold)."""
-    res = _prefix_matrix("dp")
-    for mode in PREEMPTS:
-        assert res[mode]["kv_shards"] == 2
-        assert res[mode]["shard_local"]

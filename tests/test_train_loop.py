@@ -65,6 +65,53 @@ def test_grad_compression_trains(tiny_cfg):
     assert last < first
 
 
+def test_failed_step_without_checkpoint_raises_its_own_error(
+        tiny_cfg, monkeypatch):
+    """No checkpoint to roll back to: the step's own error surfaces at
+    once — a retry would re-call a step whose state was donated."""
+    from repro.runtime import train_loop
+    calls = []
+
+    def failing_step(cfg, opts, dist=None):
+        real = make_train_step(cfg, opts, dist)
+
+        def step(state, batch):
+            calls.append(1)
+            if len(calls) == 2:
+                raise RuntimeError("step blew up")
+            return real(state, batch)
+        return step
+
+    monkeypatch.setattr(train_loop, "make_train_step", failing_step)
+    ds = SyntheticTokens(tiny_cfg, batch=4, seq=16, seed=0)
+    with pytest.raises(RuntimeError, match="step blew up"):
+        train(tiny_cfg, steps=4, batch_source=ds, jit=False,
+              adaptive=False, max_retries=2)
+    assert len(calls) == 2
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: used as is, nothing set in code;
+    unset: a fixed, git-ignored directory inside the checkout."""
+    import os
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split(), "cache dir not ignored"
+
+
 def test_grad_accum_matches_full_batch(tiny_cfg):
     """2 microbatches of 4 == 1 batch of 8 (same grads, fp32)."""
     ds = SyntheticTokens(tiny_cfg, batch=8, seq=32, seed=0)
