@@ -68,42 +68,52 @@ def _resolve_partitions(cfg: ArchConfig, t_local: int, mode: str) -> int:
 
 def _chunk_fn(params, chunk, *, cfg: ArchConfig, ep_axis: Optional[str],
               ep_size: int, cap: int, use_kernel: bool):
-    """route -> dispatch -> A2A -> expert FFN -> A2A -> combine."""
+    """route -> dispatch -> A2A -> expert FFN -> A2A -> combine, each
+    stage under its ``moe.*`` device scope."""
     m = cfg.moe
     e_total = m.num_experts
     e_local = e_total // ep_size
     t = chunk.shape[0]
 
-    probs, eidx, aux = R.route(params["router"], chunk, cfg)
-    dest, valid = D.dispatch_plan(eidx, e_total, cap)
-    buf = D.dispatch(chunk, dest, e_total, cap)          # [E, cap, M]
+    with jax.named_scope("moe.route"):
+        probs, eidx, aux = R.route(params["router"], chunk, cfg)
+    with jax.named_scope("moe.dispatch"):
+        dest, valid = D.dispatch_plan(eidx, e_total, cap)
+        buf = D.dispatch(chunk, dest, e_total, cap)      # [E, cap, M]
 
     if ep_size > 1:
-        buf = buf.reshape(ep_size, e_local, cap, -1)
-        buf = jax.lax.all_to_all(buf, ep_axis, split_axis=0, concat_axis=0)
-        buf = buf.reshape(ep_size * e_local, cap, -1)    # src-major
+        with jax.named_scope("moe.a2a_dispatch"):
+            buf = buf.reshape(ep_size, e_local, cap, -1)
+            buf = jax.lax.all_to_all(buf, ep_axis, split_axis=0,
+                                     concat_axis=0)
+            buf = buf.reshape(ep_size * e_local, cap, -1)    # src-major
     t_di = checkpoint_name(buf, "t_di")                  # paper's T_DI
-    if ep_size > 1:
-        # [ep(src), e_local, cap, M] -> [e_local, ep*cap, M]
-        ein = t_di.reshape(ep_size, e_local, cap, -1).transpose(1, 0, 2, 3)
-        ein = ein.reshape(e_local, ep_size * cap, -1)
-    else:
-        ein = t_di
+    with jax.named_scope("moe.expert_ffn"):
+        if ep_size > 1:
+            # [ep(src), e_local, cap, M] -> [e_local, ep*cap, M]
+            ein = t_di.reshape(ep_size, e_local, cap,
+                               -1).transpose(1, 0, 2, 3)
+            ein = ein.reshape(e_local, ep_size * cap, -1)
+        else:
+            ein = t_di
+        eout = E.apply_grouped(params["experts"], ein, cfg,
+                               use_kernel=use_kernel)    # paper's T_DO
 
-    eout = E.apply_grouped(params["experts"], ein, cfg,
-                           use_kernel=use_kernel)        # paper's T_DO
-
     if ep_size > 1:
-        eout = eout.reshape(e_local, ep_size, cap, -1).transpose(1, 0, 2, 3)
-        eout = jax.lax.all_to_all(eout, ep_axis, split_axis=0,
-                                  concat_axis=0)
-        eout = eout.reshape(e_total, cap, -1)
-    out = D.combine(eout, dest, probs, t)
+        with jax.named_scope("moe.a2a_combine"):
+            eout = eout.reshape(e_local, ep_size, cap,
+                                -1).transpose(1, 0, 2, 3)
+            eout = jax.lax.all_to_all(eout, ep_axis, split_axis=0,
+                                      concat_axis=0)
+            eout = eout.reshape(e_total, cap, -1)
+    with jax.named_scope("moe.combine"):
+        out = D.combine(eout, dest, probs, t)
 
     if m.num_shared_experts:
         # always-on shared experts: dense, independent of the A2As —
         # XLA overlaps this compute with the in-flight collectives.
-        out = out + E.apply_shared(params["shared"], chunk, cfg)
+        with jax.named_scope("moe.expert_ffn"):
+            out = out + E.apply_shared(params["shared"], chunk, cfg)
     return out, aux
 
 
@@ -117,26 +127,34 @@ def _replicated_decode(params, tokens, *, cfg: ArchConfig,
     t = tokens.shape[0]
     cap = capacity_for(t, m.top_k, max(m.capacity_factor, 2.0), e_total)
 
-    probs, eidx, aux = R.route(params["router"], tokens, cfg)
-    dest, valid = D.dispatch_plan(eidx, e_total, cap)
-    buf = D.dispatch(tokens, dest, e_total, cap)         # [E, cap, M]
-    if ep_size > 1:
-        my = jax.lax.axis_index(ep_axis)
-        local = jax.lax.dynamic_slice_in_dim(buf, my * e_local, e_local, 0)
-    else:
-        local = buf
-    eout = E.apply_grouped(params["experts"], local, cfg,
-                           use_kernel=use_kernel)
-    if ep_size > 1:
-        full = jnp.zeros_like(buf)
-        full = jax.lax.dynamic_update_slice_in_dim(full, eout,
-                                                   my * e_local, 0)
-        full = jax.lax.psum(full, ep_axis)
-    else:
-        full = eout
-    out = D.combine(full, dest, probs, t)
+    with jax.named_scope("moe.route"):
+        probs, eidx, aux = R.route(params["router"], tokens, cfg)
+    with jax.named_scope("moe.dispatch"):
+        dest, valid = D.dispatch_plan(eidx, e_total, cap)
+        buf = D.dispatch(tokens, dest, e_total, cap)     # [E, cap, M]
+        if ep_size > 1:
+            my = jax.lax.axis_index(ep_axis)
+            local = jax.lax.dynamic_slice_in_dim(buf, my * e_local,
+                                                 e_local, 0)
+        else:
+            local = buf
+    with jax.named_scope("moe.expert_ffn"):
+        eout = E.apply_grouped(params["experts"], local, cfg,
+                               use_kernel=use_kernel)
+    # the psum that stands in for the combine All-to-All is the
+    # combine's own
+    with jax.named_scope("moe.combine"):
+        if ep_size > 1:
+            full = jnp.zeros_like(buf)
+            full = jax.lax.dynamic_update_slice_in_dim(full, eout,
+                                                       my * e_local, 0)
+            full = jax.lax.psum(full, ep_axis)
+        else:
+            full = eout
+        out = D.combine(full, dest, probs, t)
     if m.num_shared_experts:
-        out = out + E.apply_shared(params["shared"], tokens, cfg)
+        with jax.named_scope("moe.expert_ffn"):
+            out = out + E.apply_shared(params["shared"], tokens, cfg)
     return out, aux
 
 

@@ -3,6 +3,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
@@ -57,17 +58,18 @@ def block_apply(params, x, *, cfg: ArchConfig, role: Dict, positions,
     x = constrain(dist, x, res_dims)
     h = norm.apply(params["mixer_norm"], x, cfg.norm)
     if mixer == "attn":
-        if causal:
-            mix, new_cache = attention.apply(
-                params["mixer"], h, cfg=cfg, positions=positions,
-                is_global=role["global_attn"], mode=mode, cache=cache,
-                positions3=positions3, dist=dist)
-        else:                                  # encoder self-attention
-            q, k, v = attention._proj_qkv(params["mixer"], h, cfg)
-            out = attention.flash_attention(q, k, v, causal=False)
-            mix = jnp.einsum("bshe,hed->bsd", out,
-                             params["mixer"]["w_o"].astype(h.dtype))
-            new_cache = None
+        with jax.named_scope("attention"):
+            if causal:
+                mix, new_cache = attention.apply(
+                    params["mixer"], h, cfg=cfg, positions=positions,
+                    is_global=role["global_attn"], mode=mode, cache=cache,
+                    positions3=positions3, dist=dist)
+            else:                              # encoder self-attention
+                q, k, v = attention._proj_qkv(params["mixer"], h, cfg)
+                out = attention.flash_attention(q, k, v, causal=False)
+                mix = jnp.einsum("bshe,hed->bsd", out,
+                                 params["mixer"]["w_o"].astype(h.dtype))
+                new_cache = None
     elif mixer == "mamba":
         mix, new_cache = mamba.apply(params["mixer"], h, cfg=cfg, mode=mode,
                                      cache=cache)
@@ -83,7 +85,9 @@ def block_apply(params, x, *, cfg: ArchConfig, role: Dict, positions,
 
     if enc_kv is not None:                     # enc-dec cross attention
         h = norm.apply(params["cross_norm"], x, cfg.norm)
-        x = x + attention.apply_cross(params["cross"], h, enc_kv, cfg=cfg)
+        with jax.named_scope("attention"):
+            x = x + attention.apply_cross(params["cross"], h, enc_kv,
+                                          cfg=cfg)
 
     if mixer in ("mlstm", "slstm"):
         return x, aux, new_cache

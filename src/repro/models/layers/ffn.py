@@ -31,6 +31,11 @@ def specs(d_model: int, d_ff: int, gated: bool):
 
 
 def apply(params, x, *, act: str = "silu", gated: bool = True, dist=None):
+    with jax.named_scope("ffn"):
+        return _apply(params, x, act, gated, dist)
+
+
+def _apply(params, x, act: str, gated: bool, dist):
     from repro.distributed.context import constrain
     dt = x.dtype
     h = jnp.einsum("...d,df->...f", x, params["w_up"].astype(dt))

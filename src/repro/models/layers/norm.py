@@ -1,6 +1,7 @@
 """RMSNorm / LayerNorm (pre-norm convention, fp32 statistics)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.models.module import Spec
@@ -16,6 +17,11 @@ def specs(d: int, kind: str):
 
 
 def apply(params, x, kind: str, eps: float = 1e-6):
+    with jax.named_scope("norm"):
+        return _apply(params, x, kind, eps)
+
+
+def _apply(params, x, kind: str, eps: float):
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     if kind == "rmsnorm":

@@ -92,9 +92,10 @@ def forward(params, batch, cfg: ArchConfig, *, mode: str = "train",
     else:
         positions = None  # filled after embeds are known
 
-    x = embedding.embed(params["embed"], tokens, cfg,
-                        positions=positions if mode == "decode" else None,
-                        dtype=dt)
+    with jax.named_scope("embed"):
+        x = embedding.embed(params["embed"], tokens, cfg,
+                            positions=positions if mode == "decode" else None,
+                            dtype=dt)
     if batch.get("embeds") is not None:
         x = jnp.concatenate([batch["embeds"].astype(dt), x], axis=1)
     s = x.shape[1]
@@ -106,7 +107,8 @@ def forward(params, batch, cfg: ArchConfig, *, mode: str = "train",
         else:
             positions = jnp.broadcast_to(base, (b, s))
         if cfg.positional == "learned":
-            x = x + params["embed"]["pos"][positions[0]].astype(dt)[None]
+            with jax.named_scope("embed"):
+                x = x + params["embed"]["pos"][positions[0]].astype(dt)[None]
     positions3 = batch.get("positions3")
 
     roles = cfg.layer_roles()
@@ -182,9 +184,10 @@ def forward(params, batch, cfg: ArchConfig, *, mode: str = "train",
         new_layers = None
 
     x = norm.apply(params["final_norm"], x, cfg.norm)
-    logits = embedding.logits(params["embed"], x, cfg)
-    if dist is not None:
-        logits = dist.constrain(logits, ("dp", None, "tp"))
+    with jax.named_scope("lm_head"):
+        logits = embedding.logits(params["embed"], x, cfg)
+        if dist is not None:
+            logits = dist.constrain(logits, ("dp", None, "tp"))
 
     new_cache = None
     if cache is not None:
@@ -229,8 +232,9 @@ def loss_fn(params, batch, cfg: ArchConfig, dist=None,
     labels = batch["labels"]
     # logits cover (embeds + tokens); labels align with the LAST S_text
     # positions (stub-embeds positions carry label -1 = masked anyway)
-    logits = logits[:, -labels.shape[1]:]
-    ce = cross_entropy(logits, labels)
+    with jax.named_scope("lm_head"):
+        logits = logits[:, -labels.shape[1]:]
+        ce = cross_entropy(logits, labels)
     loss = ce + aux["aux_loss"] + aux["z_loss"]
     return loss, {"ce": ce, "aux_loss": aux["aux_loss"],
                   "z_loss": aux["z_loss"], "loss": loss}

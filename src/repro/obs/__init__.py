@@ -11,11 +11,18 @@ Module map:
   Perfetto-loadable export) and the no-op ``NullTracer``.
 - ``export``   — ``MetricsServer``: background HTTP thread serving
   ``/metrics`` + ``/healthz``.
+- ``scopes``   — the process-wide scope table: for each compiled
+  program, the innermost ``repro`` device scope (``sample``,
+  ``moe.expert_ffn``, ``optimizer``, ...) of every HLO instruction, so
+  a profiler trace's device ops can be put down to a layer.
 
 The unit the rest of the codebase passes around is :class:`Recorder`:
 a registry (always real, so ``Engine.stats()`` and ``/metrics`` read
 one source of truth) plus a tracer (``NullTracer`` unless span
-recording was requested). "Telemetry disabled" — the default — means
+recording was requested). Spans of either tracer reach the JAX
+profiler while a profiler session is active, and every Recorder
+installs the process's one GC hook (``host.gc`` spans,
+``repro_gc_pause_seconds``). "Telemetry disabled" — the default — means
 the null tracer and no exporter thread; the registry itself is plain
 counter arithmetic on the host and is never consulted inside jitted
 code, so the disabled path adds no jit traces and no measurable
@@ -29,7 +36,7 @@ from typing import Optional
 from .export import MetricsServer
 from .metrics import Counter, Gauge, Histogram, Registry, quantile
 from .trace import (NullTracer, PID_ENGINE, PID_INGRESS, PID_REQUESTS,
-                    PID_RESOLVER, Tracer)
+                    PID_RESOLVER, Tracer, install_gc_hook)
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsServer",
            "NullTracer", "PID_ENGINE", "PID_INGRESS", "PID_REQUESTS",
@@ -46,6 +53,19 @@ class Recorder:
                  tracer: Optional[NullTracer] = None):
         self.registry = registry if registry is not None else Registry()
         self.tracer = tracer if tracer is not None else NullTracer()
+        self._m_gc = self.registry.histogram(
+            "repro_gc_pause_seconds", "garbage-collection pauses",
+            ["generation"])
+        install_gc_hook(self)
+
+    def gc_pause(self, generation: int, t0: float, t1: float) -> None:
+        """One collection, ``t0``..``t1`` on ``time.perf_counter``."""
+        self._m_gc.labels(generation=generation).observe(t1 - t0)
+        if self.tracer.enabled:
+            self.tracer._emit({"ph": "X", "name": "host.gc",
+                               "pid": PID_ENGINE, "tid": 1,
+                               "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6,
+                               "args": {"generation": generation}})
 
     @property
     def tracing(self) -> bool:

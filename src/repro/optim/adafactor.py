@@ -46,6 +46,11 @@ def abstract_state(abstract_params, cfg: AdafactorConfig):
 
 
 def update(grads, state, params, cfg: AdafactorConfig, lr_scale=1.0):
+    with jax.named_scope("optimizer"):
+        return _update(grads, state, params, cfg, lr_scale)
+
+
+def _update(grads, state, params, cfg: AdafactorConfig, lr_scale):
     count = state["count"] + 1
     beta2 = 1.0 - count.astype(jnp.float32) ** (-cfg.decay)
 

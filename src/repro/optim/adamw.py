@@ -65,6 +65,11 @@ def abstract_state(abstract_params, cfg: AdamWConfig):
 
 
 def update(grads, state, params, cfg: AdamWConfig, lr_scale=1.0):
+    with jax.named_scope("optimizer"):
+        return _update(grads, state, params, cfg, lr_scale)
+
+
+def _update(grads, state, params, cfg: AdamWConfig, lr_scale):
     count = state["count"] + 1
     b1c = 1 - cfg.b1 ** count.astype(jnp.float32)
     b2c = 1 - cfg.b2 ** count.astype(jnp.float32)

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.core.selector import Resolver
 from repro.core.types import TPU_V5E, HardwareSpec, Strategy
-from repro.obs import Recorder
+from repro.obs import Recorder, scopes
 from repro.distributed import sharding as shd
 from repro.distributed.compression import compress_with_feedback
 from repro.models.api import get_model
@@ -113,7 +113,28 @@ def _jit_step(fn, shardings, donate: bool):
     if shardings is not None:
         kw.update(in_shardings=(shardings, None),
                   out_shardings=(shardings, None))
-    return jax.jit(fn, **kw)
+    return _ScopedStep(jax.jit(fn, **kw))
+
+
+class _ScopedStep:
+    """A jitted train step that puts its program's device scopes into
+    the process's scope table (``repro.obs.scopes``) after its first
+    call. Lowering with that call's arguments (donated ones included)
+    hits jit's caches: nothing is traced or compiled again."""
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+        self._published = False
+
+    def __call__(self, *args):
+        out = self.jitted(*args)
+        if not self._published:
+            self._published = True
+            self._publish(args)
+        return out
+
+    def _publish(self, args) -> None:
+        scopes.register_compiled(self.jitted.lower(*args).compile())
 
 
 def abstract_state(cfg: ArchConfig, opts: TrainOptions) -> Dict[str, Any]:

@@ -83,7 +83,7 @@ from repro.core.strategies import host_offload_supported
 from repro.core.types import TPU_V5E, HardwareSpec, Strategy
 from repro.distributed.context import make_serving_context
 from repro.models.api import get_model, serving_support
-from repro.obs import PID_ENGINE, PID_REQUESTS, Recorder, quantile
+from repro.obs import PID_ENGINE, PID_REQUESTS, Recorder, quantile, scopes
 from repro.serve.adaptive import PrefillBucketAdaptive, force_adaptive
 from repro.serve.state_cache import KV_SHARDINGS, make_state_cache
 from repro.serve.request import Request, RequestState
@@ -274,7 +274,6 @@ class Engine:
         # truncated output and (often) no tokens at all, so folding them
         # into the latency/TTFT percentiles would corrupt the SLO story
         self.cancelled: List[Request] = []
-        self.metrics: Dict[str, Any] = {}
         self._init_metrics()
 
     # -- telemetry -------------------------------------------------------
@@ -424,6 +423,10 @@ class Engine:
                                       pos),
                         jnp.isfinite(logits).all(axis=-1),
                         self._pin_pools(new_pools))
+            # one program name per (bucket, n, strategy): the scope
+            # table is keyed by the name a profiler trace prints
+            body.__name__ = body.__qualname__ = "prefill_b{}_n{}_{}".format(
+                bucket, *key[1])
             fn = jax.jit(body)
             self.prefill_rejits += 1
             self._m_compiles.inc()
@@ -553,8 +556,9 @@ class Engine:
         the number of programs compiled."""
         kv = self.kv
         before = self.prefill_rejits
+        ran = [(self._decode_fn, self._idle_decode_args())]
         with self._mesh_scope():
-            out = self._decode_fn(*self._idle_decode_args())
+            out = self._decode_fn(*ran[0][1])
             jax.block_until_ready(out[0])
         buckets, c = set(), 1
         while c < self.scheduler.chunk:
@@ -563,16 +567,27 @@ class Engine:
         buckets.add(self.adaptive.bucket_of(self.scheduler.chunk))
         for b in sorted(buckets):
             fn = self._prefill_fn(b, self.adaptive.cfg_for(b))
+            args = (self.params, kv.pools, kv.device_page_table(0),
+                    kv.device_lens(0),
+                    self._put(np.zeros((1, b), np.int32)),
+                    self._put(np.asarray(0, np.int32)),
+                    self._put(np.zeros((1,), np.int32)),
+                    self._put(kv.sink_row(0)),
+                    *self._sample_args([None]))
             with self._mesh_scope():
-                out = fn(self.params, kv.pools, kv.device_page_table(0),
-                         kv.device_lens(0),
-                         self._put(np.zeros((1, b), np.int32)),
-                         self._put(np.asarray(0, np.int32)),
-                         self._put(np.zeros((1,), np.int32)),
-                         self._put(kv.sink_row(0)),
-                         *self._sample_args([None]))
+                out = fn(*args)
                 jax.block_until_ready(out[0])
+            ran.append((fn, args))
+        self._publish_scopes(ran)
         return 1 + self.prefill_rejits - before
+
+    def _publish_scopes(self, ran) -> None:
+        """Put the device scopes of each (program, arguments it ran
+        with) into the process's scope table. Lowering a program that
+        has run hits jit's caches: nothing is traced or compiled."""
+        with self._mesh_scope():
+            for fn, args in ran:
+                scopes.register_compiled(fn.lower(*args).compile())
 
     def _idle_decode_args(self) -> tuple:
         """Decode-step arguments with every slot inactive (all KV writes
@@ -586,8 +601,8 @@ class Engine:
                 *self._sample_args([None] * kv.max_slots, slots=True))
 
     def compiled_decode_text(self) -> str:
-        """HLO text of the compiled decode program. Lowering re-traces
-        the decode body, so ``decode_traces`` counts one more trace."""
+        """HLO text of the compiled decode program (from jit's caches
+        once the program has run; compiled here otherwise)."""
         with self._mesh_scope():
             return self._decode_fn.lower(
                 *self._idle_decode_args()).compile().as_text()
@@ -677,28 +692,40 @@ class Engine:
 
     # -- engine iteration ------------------------------------------------
     def step(self) -> Dict[str, Any]:
-        """Admit, then run one jitted step (prefill chunk or decode)."""
+        """Admit, then run one jitted step (prefill chunk or decode).
+
+        Each phase is a span inside ``engine.step`` (args: kind):
+        ``engine.admit`` (admission and the next action),
+        ``engine.kv_grow`` (page growth, copy-on-write and preemption),
+        ``engine.inputs`` (host arrays and their uploads),
+        ``engine.dispatch`` (the jitted call), ``engine.sync`` (the first
+        host read of its outputs) and ``engine.emit`` (tokens, retires).
+        """
         t0 = time.perf_counter()
-        with self.obs.tracer.span("engine.step",
-                                  args={"step": self.step_count}) as sp:
-            # storm injection (tests/benchmarks): constant-state caches
-            # hold O(1) bytes per slot and never run dry, so preemption
-            # storms must be forced rather than provoked by a small pool
-            if (self.opts.storm_every and self.opts.preempt != "never"
-                    and self.scheduler.running):
-                self._storm_tick += 1
-                if self._storm_tick >= self.opts.storm_every:
-                    self._storm_tick = 0
-                    victim = self._pick_victim()
-                    if victim is not None:
-                        self._do_preempt(victim)
-            self.scheduler.admit()
-            if not (self.preempts["recompute"]
-                    or self.preempts["offload"]):
-                self.peak_running_preempt_free = max(
-                    self.peak_running_preempt_free,
-                    len(self.scheduler.running))
-            action, req = self.scheduler.next_action()
+        tracer = self.obs.tracer
+        with tracer.span("engine.step",
+                         args={"step": self.step_count}) as sp:
+            with tracer.span("engine.admit") as phase:
+                # storm injection (tests/benchmarks): constant-state
+                # caches hold O(1) bytes per slot and never run dry, so
+                # preemption storms must be forced rather than provoked
+                # by a small pool
+                if (self.opts.storm_every and self.opts.preempt != "never"
+                        and self.scheduler.running):
+                    self._storm_tick += 1
+                    if self._storm_tick >= self.opts.storm_every:
+                        self._storm_tick = 0
+                        victim = self._pick_victim()
+                        if victim is not None:
+                            self._do_preempt(victim)
+                self.scheduler.admit()
+                if not (self.preempts["recompute"]
+                        or self.preempts["offload"]):
+                    self.peak_running_preempt_free = max(
+                        self.peak_running_preempt_free,
+                        len(self.scheduler.running))
+                action, req = self.scheduler.next_action()
+                phase["kind"] = action
             sp["kind"] = action
             info: Dict[str, Any] = {"kind": action}
             if action == "prefill":
@@ -712,56 +739,56 @@ class Engine:
         self._m_steps.labels(kind=action).inc()
         self._m_step_s.labels(kind=action).observe(
             time.perf_counter() - t0)
-        info.update(cache_bytes=self.kv.cache_bytes,
-                    kv_used_bytes=self.kv.used_bytes,
-                    free_pages=self.kv.free_units,
-                    running=len(self.scheduler.running),
-                    waiting=len(self.scheduler.waiting),
-                    preempted=len(self.scheduler.resuming))
-        self.metrics = info
         return info
 
     def _run_prefill(self, req: Request) -> Dict[str, Any]:
         kv, slot = self.kv, req.slot
+        tracer, kind = self.obs.tracer, {"kind": "prefill"}
         c = min(self.scheduler.chunk, req.remaining_prefill)
-        if not self._ensure(slot, int(kv.lens[slot]) + c):
-            return {"tokens": 0, "rid": req.rid, "self_preempted": True}
-        bucket = self.adaptive.bucket_of(c)
-        rcfg = self.adaptive.cfg_for(bucket)
-        fn = self._prefill_fn(bucket, rcfg)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :c] = req.prefill_tokens[req.prefill_pos:
-                                         req.prefill_pos + c]
-        tracer = self.obs.tracer
-        with tracer.span("prefill", args={"rid": req.rid}), \
+        with tracer.span("engine.kv_grow", args=kind):
+            if not self._ensure(slot, int(kv.lens[slot]) + c):
+                return {"tokens": 0, "rid": req.rid,
+                        "self_preempted": True}
+        with tracer.span("engine.inputs", args=kind):
+            bucket = self.adaptive.bucket_of(c)
+            rcfg = self.adaptive.cfg_for(bucket)
+            fn = self._prefill_fn(bucket, rcfg)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :c] = req.prefill_tokens[req.prefill_pos:
+                                             req.prefill_pos + c]
+            args = (self.params, kv.pools, kv.device_page_table(slot),
+                    kv.device_lens(slot), self._put(toks),
+                    self._put(np.asarray(c, np.int32)),
+                    self._put(np.asarray([slot], np.int32)),
+                    self._put(kv.sink_row(slot)), *self._sample_args([req]))
+        with tracer.span("engine.dispatch", args=kind), \
              tracer.span("PREFILL", pid=PID_REQUESTS, tid=req.rid,
                          args={"chunk": c, "bucket": bucket,
                                "pos": req.prefill_pos}), \
              self._mesh_scope():
-            tok, finite, kv.pools = fn(
-                self.params, kv.pools, kv.device_page_table(slot),
-                kv.device_lens(slot), self._put(toks),
-                self._put(np.asarray(c, np.int32)),
-                self._put(np.asarray([slot], np.int32)),
-                self._put(kv.sink_row(slot)), *self._sample_args([req]))
-        self._count_nonfinite(np.asarray(finite))
-        req.prefill_pos += c
-        kv.lens[slot] += c
-        self._m_prefill_tokens.inc(c)
-        self.scheduler.prefill_advanced(req)
-        if req.remaining_prefill == 0:
-            # publish the finished prompt's full pages for later
-            # requests sharing the prefix (no-op with prefix off)
-            kv.cache_slot_prefix(slot, req.prefill_tokens)
-            req.state = RequestState.DECODE
-            tracer.begin("DECODE", pid=PID_REQUESTS, tid=req.rid)
-            req.decode_span_open = True
-            # a resumed re-prefill (recompute preemption) replays tokens
-            # that were already emitted — its final-chunk sample is the
-            # pending decode input, not a new token
-            if not req.output and req.emit(int(tok[0]),
-                                           time.perf_counter()):
-                self._retire(req)
+            tok, finite, kv.pools = fn(*args)
+        del args
+        with tracer.span("engine.sync", args=kind):
+            finite = np.asarray(finite)
+        with tracer.span("engine.emit", args=kind):
+            self._count_nonfinite(finite)
+            req.prefill_pos += c
+            kv.lens[slot] += c
+            self._m_prefill_tokens.inc(c)
+            self.scheduler.prefill_advanced(req)
+            if req.remaining_prefill == 0:
+                # publish the finished prompt's full pages for later
+                # requests sharing the prefix (no-op with prefix off)
+                kv.cache_slot_prefix(slot, req.prefill_tokens)
+                req.state = RequestState.DECODE
+                tracer.begin("DECODE", pid=PID_REQUESTS, tid=req.rid)
+                req.decode_span_open = True
+                # a resumed re-prefill (recompute preemption) replays
+                # tokens that were already emitted — its final-chunk
+                # sample is the pending decode input, not a new token
+                if not req.output and req.emit(int(tok[0]),
+                                               time.perf_counter()):
+                    self._retire(req)
         info = {"tokens": c, "bucket": bucket, "rid": req.rid}
         if rcfg.moe is not None:
             info.update(n=rcfg.moe.num_partitions,
@@ -770,41 +797,47 @@ class Engine:
 
     def _run_decode(self) -> Dict[str, Any]:
         kv = self.kv
-        # every decoding slot writes one KV position this step — grow
-        # on-demand slots first, preempting victims if the pool is dry
-        # (a victim may itself be one of the decoding slots)
-        for s in list(self.scheduler.decode_slots()):
-            req = self.scheduler.running.get(s)
-            if req is None or req.state != RequestState.DECODE:
-                continue                       # preempted by an earlier
-            self._ensure(s, int(kv.lens[s]) + 1)  # slot's growth
+        tracer, kind = self.obs.tracer, {"kind": "decode"}
+        with tracer.span("engine.kv_grow", args=kind):
+            # every decoding slot writes one KV position this step —
+            # grow on-demand slots first, preempting victims if the pool
+            # is dry (a victim may itself be one of the decoding slots)
+            for s in list(self.scheduler.decode_slots()):
+                req = self.scheduler.running.get(s)
+                if req is None or req.state != RequestState.DECODE:
+                    continue                   # preempted by an earlier
+                self._ensure(s, int(kv.lens[s]) + 1)  # slot's growth
         slots = self.scheduler.decode_slots()
         if not slots:
             return {"tokens": 0}
-        tokens = np.zeros((kv.max_slots, 1), np.int32)
-        active = np.zeros((kv.max_slots,), bool)
-        by_slot: List[Optional[Request]] = [None] * kv.max_slots
-        for s in slots:
-            req = self.scheduler.running[s]
-            tokens[s, 0] = req.output[-1]
-            active[s] = True
-            by_slot[s] = req
-        with self.obs.tracer.span("decode",
-                                  args={"slots": len(slots)}), \
+        with tracer.span("engine.inputs", args=kind):
+            tokens = np.zeros((kv.max_slots, 1), np.int32)
+            active = np.zeros((kv.max_slots,), bool)
+            by_slot: List[Optional[Request]] = [None] * kv.max_slots
+            for s in slots:
+                req = self.scheduler.running[s]
+                tokens[s, 0] = req.output[-1]
+                active[s] = True
+                by_slot[s] = req
+            args = (self.params, kv.pools, kv.device_page_table(),
+                    kv.device_lens(), self._put_slots(tokens),
+                    self._put_slots(active), self._decode_sinks,
+                    *self._sample_args(by_slot, slots=True))
+        with tracer.span("engine.dispatch", args=kind), \
              self._mesh_scope():
-            toks, finite, kv.pools = self._decode_fn(
-                self.params, kv.pools, kv.device_page_table(),
-                kv.device_lens(), self._put_slots(tokens),
-                self._put_slots(active), self._decode_sinks,
-                *self._sample_args(by_slot, slots=True))
-        toks = np.asarray(toks)
-        self._count_nonfinite(np.asarray(finite)[slots])
-        now = time.perf_counter()
-        for s in slots:
-            req = self.scheduler.running[s]
-            kv.lens[s] += 1                  # the input token's KV slot
-            if req.emit(int(toks[s]), now):
-                self._retire(req)
+            toks, finite, kv.pools = self._decode_fn(*args)
+        del args
+        with tracer.span("engine.sync", args=kind):
+            toks = np.asarray(toks)
+            finite = np.asarray(finite)
+        with tracer.span("engine.emit", args=kind):
+            self._count_nonfinite(finite[slots])
+            now = time.perf_counter()
+            for s in slots:
+                req = self.scheduler.running[s]
+                kv.lens[s] += 1                # the input token's KV slot
+                if req.emit(int(toks[s]), now):
+                    self._retire(req)
         return {"tokens": len(slots)}
 
     def _count_nonfinite(self, finite: np.ndarray) -> None:

@@ -86,10 +86,11 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, position):
     depends only on its own logits and sampling state, so batch
     composition cannot perturb it. Returns int32 ``[S]``.
     """
-    return jax.vmap(_sample_row)(
-        logits, temperature.astype(jnp.float32),
-        top_k.astype(jnp.int32), top_p.astype(jnp.float32),
-        seed.astype(jnp.int32), position.astype(jnp.int32))
+    with jax.named_scope("sample"):
+        return jax.vmap(_sample_row)(
+            logits, temperature.astype(jnp.float32),
+            top_k.astype(jnp.int32), top_p.astype(jnp.float32),
+            seed.astype(jnp.int32), position.astype(jnp.int32))
 
 
 def normalize_stops(stop) -> Tuple[Tuple[int, ...], ...]:

@@ -1,24 +1,33 @@
 """Telemetry (repro.obs): the shared nearest-rank quantile (property-
 tested against a definitional reference), the metrics registry and its
 Prometheus exposition, the Chrome-trace schema over a real preemption
-storm, the live /metrics exporter agreeing with ``Engine.stats()``, and
-the zero-cost contract of disabled telemetry (identical jit traces and
-identical tokens with the tracer on or off)."""
+storm, the engine's phase spans and GC pauses on the JAX profiler's host
+plane, the device-scope table, the live /metrics exporter agreeing with
+``Engine.stats()``, and the zero-cost contract of disabled telemetry
+(identical jit traces and identical tokens with the tracer and the
+profiler on or off)."""
 import dataclasses
+import gc
+import glob
 import json
 import math
 import urllib.error
 import urllib.request
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
 from repro.models import lm
 from repro.obs import (MetricsServer, NullTracer, Recorder, Registry,
-                       Tracer, quantile)
+                       Tracer, quantile, scopes)
 from repro.serve import Engine, EngineOptions
+
+PHASES = ("engine.admit", "engine.kv_grow", "engine.inputs",
+          "engine.dispatch", "engine.sync", "engine.emit")
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 PROMPT_LENS = (13, 29, 7, 21, 5)
 MAX_NEW = (6, 4, 8, 5, 7)
@@ -236,10 +245,219 @@ def test_trace_schema_over_preemption_storm(setup, tmp_path):
                for e in by_name["PREEMPT"])
     assert by_name["PREFILL"] and by_name["engine.step"]
 
+    # each step's phases nest inside it, each phase carries its kind,
+    # and the old per-kind child spans are gone
+    assert "prefill" not in by_name and "decode" not in by_name
+    steps = [(e["ts"], e["ts"] + e["dur"], e["args"]["kind"])
+             for e in by_name["engine.step"]]
+    for name in PHASES:
+        assert by_name[name], name
+        for e in by_name[name]:
+            assert e["ph"] == "X"
+            a, b = e["ts"], e["ts"] + e["dur"]
+            outer = [s for s in steps if s[0] <= a and b <= s[1]]
+            assert len(outer) == 1, (name, e)
+            assert e["args"]["kind"] in ("prefill", "decode")
+            if name != "engine.admit":
+                assert e["args"]["kind"] == outer[0][2]
+
     # the written file is valid JSON and identical to export()
     path = tmp_path / "trace.json"
     obs.tracer.write(str(path))
     assert json.loads(path.read_text()) == doc
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: phase spans and GC pauses on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _host_spans(trace_dir):
+    """(start_ns, end_ns, name, stats) of the program's spans on the
+    host plane of the profiler trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("engine.", "host.gc")):
+                        out.append((ev.start_ns,
+                                    ev.start_ns + ev.duration_ns, ev.name,
+                                    {k: v for k, v in ev.stats}))
+    return out
+
+
+def test_phase_and_gc_spans_on_the_profiler_host_plane(setup, tmp_path):
+    cfg, params, prompts = setup
+    eng = Engine(cfg, params, options=EngineOptions(
+        page_size=4, max_slots=3, max_seq_len=64, chunk=16, min_bucket=8))
+    for p, m in zip(prompts[:2], MAX_NEW):
+        eng.submit(p, max_new_tokens=m, arrival_s=0.0)
+    admit = eng.scheduler.admit
+
+    def admit_after_a_collection():
+        gc.collect()
+        return admit()
+
+    eng.scheduler.admit = admit_after_a_collection
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run_until_idle()
+    spans = _host_spans(tmp_path)
+    steps = [s for s in spans if s[2] == "engine.step"]
+    assert len(steps) == eng.step_count
+    assert {s[3]["kind"] for s in steps} == {"prefill", "decode"}
+
+    def inside_a_step(span):
+        return any(a <= span[0] and span[1] <= b for a, b, *_ in steps)
+
+    for name in PHASES:
+        found = [s for s in spans if s[2] == name]
+        assert found, name
+        assert all(inside_a_step(s) for s in found), name
+        assert {s[3]["kind"] for s in found} <= {"prefill", "decode"}
+    full = [s for s in spans if s[2] == "host.gc"
+            and s[3]["generation"] == 2 and inside_a_step(s)]
+    assert len(full) >= eng.step_count        # one forced in each step
+
+
+def test_gc_pauses_feed_the_histogram_and_the_tracer():
+    obs = Recorder(tracer=Tracer())
+    gc.collect(1)
+    gc.collect()
+    hist = {dict(c.labels)["generation"]: c for c in
+            obs.registry.get("repro_gc_pause_seconds").children()}
+    assert hist["1"].count >= 1 and hist["2"].count >= 1
+    assert all(v >= 0 for v in hist["2"].values())
+    ev = [e for e in obs.tracer.export()["traceEvents"]
+          if e["name"] == "host.gc"]
+    assert {e["args"]["generation"] for e in ev} >= {1, 2}
+    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in ev)
+
+
+# ---------------------------------------------------------------------------
+# the scope table
+# ---------------------------------------------------------------------------
+
+def _compiles():
+    counts = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **_: counts.append(event)
+        if event == COMPILE_EVENT else None)
+    return counts
+
+
+def test_scope_table_maps_instructions_in_scan_bodies():
+    def step(x, w):
+        with jax.named_scope("moe.route"):
+            x = jnp.tanh(x) * 2.0
+
+        def body(c, wi):
+            with jax.named_scope("moe.expert_ffn"):
+                c = jnp.sin(c @ wi)
+            return c, None
+
+        y, _ = jax.lax.scan(body, x, w)
+        with jax.named_scope("optimizer"):
+            return y - 0.1 * jnp.cos(y).sum()
+
+    fn = jax.jit(step)
+    x, w = jnp.ones((8, 8)), jnp.ones((3, 8, 8)) * 0.1
+    fn(x, w).block_until_ready()
+    compiles = _compiles()
+    compiled = fn.lower(x, w).compile()
+    name = scopes.register_compiled(compiled)
+    assert compiles == []                      # nothing compiled again
+    assert name == "jit_step"
+    text = compiled.as_text()
+    assert " while(" in text                   # the scan is a loop
+    ops = scopes.table()[name]
+    dots = [ln.split(" = ")[0].strip().removeprefix("ROOT ").lstrip("%")
+            for ln in text.splitlines() if " dot(" in ln]
+    assert dots and all(ops[d] == "moe.expert_ffn" for d in dots)
+    assert set(ops.values()) >= {"moe.route", "moe.expert_ffn",
+                                 "optimizer"}
+    assert scopes.innermost(
+        "jit(f)/transpose(jvp(moe.route))/moe.expert_ffn/dot") == \
+        "moe.expert_ffn"
+    assert scopes.innermost("jit(f)/moe.router/paged_attention/x") is None
+
+
+HOISTED = """HloModule jit_step, entry_computation_layout={(f32[2,4])->f32[2,4]}
+
+%fused_update (q0: f32[2,4], q1: f32[2,4]) -> (f32[2,4], f32[2,4]) {
+  %q0 = f32[2,4] parameter(0)
+  %q1 = f32[2,4] parameter(1)
+  %dot.20 = f32[2,4] multiply(%q0, %q1), metadata={op_name="jit(step)/transpose(jvp(moe.expert_ffn))/dot_general"}
+  %add.21 = f32[2,4] add(%dot.20, %q0), metadata={op_name="jit(step)/optimizer/add"}
+  %multiply.22 = f32[2,4] multiply(%dot.20, %q1), metadata={op_name="jit(step)/optimizer/mul"}
+  ROOT %tuple.23 = (f32[2,4], f32[2,4]) tuple(%add.21, %multiply.22)
+}
+
+%body (arg: (s32[], bf16[2,4])) -> (s32[], bf16[2,4]) {
+  %arg = (s32[], bf16[2,4]) parameter(0)
+  %get-tuple-element.1 = s32[] get-tuple-element(%arg), index=0
+  %get-tuple-element.2 = bf16[2,4] get-tuple-element(%arg), index=1
+  %dynamic-slice.3 = bf16[1,4] dynamic-slice(%get-tuple-element.2, %get-tuple-element.1), dynamic_slice_sizes={1,4}, metadata={op_name="jit(step)/while/body/dynamic_slice"}
+  %fusion.4 = bf16[1,4] fusion(%dynamic-slice.3), kind=kLoop, calls=%f, metadata={op_name="jit(step)/while/body/closed_call/moe.expert_ffn/dot_general"}
+  ROOT %tuple.5 = (s32[], bf16[2,4]) tuple(%get-tuple-element.1, %get-tuple-element.2)
+}
+
+ENTRY %main (p: f32[2,4]) -> f32[2,4] {
+  %p = f32[2,4] parameter(0)
+  %convert.6 = bf16[2,4] convert(%p)
+  %constant.7 = s32[] constant(0)
+  %tuple.8 = (s32[], bf16[2,4]) tuple(%constant.7, /*index=1*/%convert.6)
+  %while.9 = (s32[], bf16[2,4]) while(%tuple.8), condition=%c, body=%body, metadata={op_name="jit(step)/while"}
+  %reduce-window.10 = f32[2,4] reduce-window(%p), metadata={op_name="reduce_window_sum"}
+  %fusion.11 = f32[2,4] fusion(%reduce-window.10), kind=kLoop, calls=%g, metadata={op_name="jit(step)/sample/cumsum"}
+  %copy.12 = f32[2,4] copy(%p)
+  %fusion.14 = (f32[2,4], f32[2,4]) fusion(%p, %p), kind=kOutput, calls=%fused_update, metadata={op_name="jit(step)/transpose(jvp(moe.expert_ffn))/dot_general"}
+  ROOT %fusion.13 = f32[2,4] fusion(%copy.12, %fusion.11), kind=kLoop, calls=%h, metadata={op_name="jit(step)/add"}
+}
+"""
+
+
+def test_compiler_made_ops_take_their_consumers_scope():
+    """A cast the compiler hoisted out of the layer loop (no op_name)
+    counts where the loop body uses it; a bare op_name is the
+    compiler's too; a value that ends in an unscoped op stays out; a
+    weight gradient fused with the update that writes the parameter
+    and its moment counts as the optimizer."""
+    name = scopes.register(HOISTED)
+    ops = scopes.table()[name]
+    assert ops["convert.6"] == "moe.expert_ffn"
+    assert ops["fusion.4"] == "moe.expert_ffn"
+    assert ops["reduce-window.10"] == "sample"
+    assert "copy.12" not in ops and "fusion.13" not in ops
+    assert "dynamic-slice.3" not in ops        # traced, in no scope
+    assert ops["fusion.14"] == "optimizer"
+
+
+def test_warmup_publishes_every_program_and_compiles_nothing(setup,
+                                                             monkeypatch):
+    cfg, params, _ = setup
+    seen = []
+    publish = Engine._publish_scopes
+
+    def counted(self, ran):
+        compiles = _compiles()
+        traces = (self.decode_traces, self.prefill_traces)
+        publish(self, ran)
+        seen.append((list(compiles), traces,
+                     (self.decode_traces, self.prefill_traces), len(ran)))
+
+    monkeypatch.setattr(Engine, "_publish_scopes", counted)
+    eng = Engine(cfg, params, options=EngineOptions(
+        page_size=4, max_slots=3, max_seq_len=64, chunk=16, min_bucket=8))
+    eng.warmup()
+    (compiles, before, after, programs), = seen
+    assert compiles == [] and before == after and programs == 3
+    table = scopes.table()
+    assert {"sample", "attention"} <= set(table["jit__decode_step"].values())
+    for b in (8, 16):
+        name, = [k for k in table if k.startswith(f"jit_prefill_b{b}_")]
+        assert "sample" in table[name].values()
 
 
 # ---------------------------------------------------------------------------
@@ -343,14 +561,21 @@ def test_stats_quantiles_use_shared_util(setup):
 # zero-cost when disabled: tokens and jit traces identical on vs off
 # ---------------------------------------------------------------------------
 
-def test_telemetry_on_off_identical_traces_and_tokens(setup):
+def test_telemetry_on_off_identical_traces_and_tokens(setup, tmp_path):
     cfg, params, prompts = setup
     off = _run(cfg, params, prompts)          # default no-op recorder
     on = _run(cfg, params, prompts, obs=Recorder(tracer=Tracer()))
-    assert on.decode_traces == off.decode_traces
-    assert on.prefill_traces == off.prefill_traces
-    assert [r.output for r in sorted(on.done, key=lambda r: r.rid)] == \
-           [r.output for r in sorted(off.done, key=lambda r: r.rid)]
+    # the profiler sink: spans of both tracers become TraceMe events
+    with jax.profiler.trace(str(tmp_path)):
+        prof = _run(cfg, params, prompts, obs=Recorder(tracer=Tracer()))
+        prof_null = _run(cfg, params, prompts)
+    assert any(s[2] == "engine.dispatch" for s in _host_spans(tmp_path))
+    for eng in (on, prof, prof_null):
+        assert eng.decode_traces == off.decode_traces
+        assert eng.prefill_traces == off.prefill_traces
+        assert eng.prefill_rejits == off.prefill_rejits
+        assert [r.output for r in sorted(eng.done, key=lambda r: r.rid)] \
+            == [r.output for r in sorted(off.done, key=lambda r: r.rid)]
     # default recorder still counts jit traces in its registry
     snap = off.obs.registry.snapshot()
     assert snap["repro_jit_traces_total"]['body="decode"'] == \

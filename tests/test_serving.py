@@ -192,13 +192,15 @@ def test_engine_surfaces_kv_metrics(setup):
     cfg, params, prompts, _ = setup
     eng = _engine(cfg, params)
     eng.submit(prompts[2], max_new_tokens=3)
-    info = eng.step()
+    eng.step()
     leaves = jax.tree_util.tree_leaves(eng.kv.pools)
-    assert info["cache_bytes"] == \
+    assert eng.stats()["cache_bytes"] == \
         sum(x.size * x.dtype.itemsize for x in leaves)
-    assert info["kv_used_bytes"] > 0                # pages reserved
+    used = eng.obs.registry.gauge("repro_kv_used_bytes")
+    assert used.value > 0                           # pages reserved
     eng.run_until_idle()
-    assert eng.metrics["kv_used_bytes"] == 0        # all pages returned
+    eng.stats()                                     # refreshes the gauges
+    assert used.value == 0                          # all pages returned
     assert eng.stats()["peak_kv_used_bytes"] > 0
 
 

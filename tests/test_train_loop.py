@@ -126,3 +126,39 @@ def test_grad_accum_matches_full_batch(tiny_cfg):
     w1 = jax.tree_util.tree_leaves(o1["params"])[0]
     w2 = jax.tree_util.tree_leaves(o2["params"])[0]
     assert jnp.allclose(w1, w2, atol=1e-4)
+
+
+def test_train_step_publishes_its_scopes_and_the_profiler_moves_no_loss(
+        tiny_cfg, tmp_path, monkeypatch):
+    """After its first call the jitted step puts its device scopes into
+    the scope table, from jit's caches (no compile); a profiler session
+    around training changes no loss."""
+    from repro.obs import scopes
+    from repro.runtime import train_loop
+
+    published = []
+    publish = train_loop._ScopedStep._publish
+
+    def counted(self, args):
+        compiles = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, duration, **_: compiles.append(event)
+            if event == "/jax/core/compile/backend_compile_duration"
+            else None)
+        publish(self, args)
+        published.append(list(compiles))
+
+    monkeypatch.setattr(train_loop._ScopedStep, "_publish", counted)
+    ds = SyntheticTokens(tiny_cfg, batch=4, seq=16, seed=0)
+    opts = TrainOptions(lr=3e-3, warmup=1, total_steps=10)
+    _, off = train(tiny_cfg, steps=3, batch_source=ds, opts=opts,
+                   adaptive=False)
+    with jax.profiler.trace(str(tmp_path)):
+        _, on = train(tiny_cfg, steps=3, batch_source=ds, opts=opts,
+                      adaptive=False)
+    assert [h["loss"] for h in on] == [h["loss"] for h in off]
+    assert published == [[], []]               # once per step object
+    ops = scopes.table()["jit_train_step"]
+    assert {"optimizer", "lm_head", "attention", "moe.route",
+            "moe.dispatch", "moe.expert_ffn", "moe.combine"} <= \
+        set(ops.values())
