@@ -87,7 +87,8 @@ from repro.obs import PID_ENGINE, PID_REQUESTS, Recorder, quantile, scopes
 from repro.serve.adaptive import PrefillBucketAdaptive, force_adaptive
 from repro.serve.state_cache import KV_SHARDINGS, make_state_cache
 from repro.serve.request import Request, RequestState
-from repro.serve.sampling import SamplingParams, sample_tokens
+from repro.serve.sampling import (SAMPLE_PATHS, SamplingParams,
+                                  sample_path, sample_tokens)
 from repro.serve.scheduler import Scheduler
 
 log = logging.getLogger("repro.serve")
@@ -302,6 +303,10 @@ class Engine:
         self._m_nonfinite = reg.counter(
             "repro_nonfinite_logit_rows_total",
             "served logit rows holding a NaN or inf")
+        self._m_sample_path = reg.counter(
+            "repro_sample_path_total",
+            "sampler branch of each jitted prefill or decode call",
+            ["path"])
         self._m_step_s = reg.histogram(
             "repro_step_seconds", "host wall time per engine step",
             ["kind"])
@@ -457,6 +462,9 @@ class Engine:
             temp[i], top_k[i], top_p[i], seed[i] = (
                 sp.temperature, sp.top_k, sp.top_p, sp.seed)
             pos[i] = len(r.output)
+        # the branch sample_tokens will take on device, by the same rule
+        self._m_sample_path.labels(
+            path=SAMPLE_PATHS[sample_path(temp)]).inc()
         return tuple(put(a) for a in (temp, top_k, top_p, seed, pos))
 
     # -- serve-side wall-clock measurement -------------------------------

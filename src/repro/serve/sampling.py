@@ -8,6 +8,21 @@ whole continuous batch — greedy and sampled slots mixed — runs through
 one program. ``temperature <= 0`` means greedy (exact ``argmax``, the
 golden-test reference path).
 
+The program holds two branches and picks one per call on device
+(``lax.switch`` over the whole batch, outside the per-row ``vmap``,
+where a ``cond`` would run every branch), from the values of the
+temperatures (:func:`sample_path`, which the engine's host-side
+``repro_sample_path_total`` counter shares):
+
+- ``greedy``: every row has ``temperature <= 0``; each row's float32
+  ``argmax``, no sort, no softmax, no random bits;
+- ``sorted``: some row samples; every row sorts its vocabulary, and a
+  sampled row's keep set is decided by rank (``top_k <= 0`` and
+  ``top_p`` outside (0, 1) keep the whole vocabulary), a greedy row
+  takes its ``argmax``.
+
+A greedy row gets the same token in both branches.
+
 Determinism: each sampled token's PRNG key is
 ``fold_in(PRNGKey(seed), position)`` where ``position`` is the index of
 the token being generated — a pure function of the request, independent
@@ -26,11 +41,14 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1.0e30
+# sample_tokens' branches, by the index sample_path gives
+SAMPLE_PATHS = ("greedy", "sorted")
 
-__all__ = ["SamplingParams", "normalize_stops", "sample_tokens",
-           "stop_hit"]
+__all__ = ["SAMPLE_PATHS", "SamplingParams", "normalize_stops",
+           "sample_path", "sample_tokens", "stop_hit"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,12 +69,24 @@ class SamplingParams:
         return self.temperature <= 0.0
 
 
-def _sample_row(logits, temperature, top_k, top_p, seed, position):
-    """One slot: masked top-k/top-p categorical sample (or argmax)."""
-    v = logits.shape[-1]
-    lg = logits.astype(jnp.float32)
-    greedy = temperature <= 0.0
-    scaled = lg / jnp.where(greedy, 1.0, temperature)
+def sample_path(temperature):
+    """Index into :data:`SAMPLE_PATHS` of the branch a batch takes.
+
+    Takes the per-slot temperatures as numpy (the engine's host count)
+    or jax (the device branch) alike: 0 when every row is greedy, 1 when
+    some row samples."""
+    return (~(temperature <= 0.0)).any().astype(np.int32)
+
+
+def _greedy_row(logits, *_):
+    """One slot of an all-greedy batch: its float32 argmax."""
+    return jnp.argmax(logits.astype(jnp.float32)).astype(jnp.int32)
+
+
+def _keep(scaled, top_k, top_p):
+    """One row's keep set in the sorted branch: the ``top_k`` highest
+    ranks within the smallest prefix whose mass reaches ``top_p``."""
+    v = scaled.shape[-1]
     # rank in the descending sort, ties broken by vocab index (argsort
     # is stable): the keep set is decided by rank, never by comparing
     # against a threshold *value* — a value cut keeps every entry tied
@@ -72,10 +102,26 @@ def _sample_row(logits, temperature, top_k, top_p, seed, position):
     probs = jax.nn.softmax(order)
     below = jnp.cumsum(probs) - probs                    # mass before each
     n_keep = jnp.maximum(jnp.sum(below < p_eff), 1)
-    masked = jnp.where(rank < jnp.minimum(k_eff, n_keep), scaled, NEG_INF)
+    # filter off keeps every token: in float32 the cumulative mass
+    # passes 1 before the tail, and below < 1 would drop the tail
+    n_keep = jnp.where(p_eff >= 1.0, v, n_keep)
+    return rank < jnp.minimum(k_eff, n_keep)
+
+
+def _sorted_row(logits, temperature, top_k, top_p, seed, position):
+    """One slot: masked top-k/top-p categorical sample (or argmax)."""
+    lg = logits.astype(jnp.float32)
+    greedy = temperature <= 0.0
+    scaled = lg / jnp.where(greedy, 1.0, temperature)
+    masked = jnp.where(_keep(scaled, top_k, top_p), scaled, NEG_INF)
     key = jax.random.fold_in(jax.random.PRNGKey(seed), position)
     sampled = jax.random.categorical(key, masked)
     return jnp.where(greedy, jnp.argmax(lg), sampled).astype(jnp.int32)
+
+
+# built once: lax.switch caches its traced branches by function, so an
+# eager call outside jit then compiles the conditional once, not per call
+_BRANCHES = (jax.vmap(_greedy_row), jax.vmap(_sorted_row))
 
 
 def sample_tokens(logits, temperature, top_k, top_p, seed, position):
@@ -84,13 +130,14 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, position):
     logits ``[S, V]``; all other args ``[S]`` (float32 temperature/top_p,
     int32 top_k/seed/position). Rows are independent: a slot's token
     depends only on its own logits and sampling state, so batch
-    composition cannot perturb it. Returns int32 ``[S]``.
+    composition cannot perturb it. The branch (:func:`sample_path`) is
+    chosen on device for the whole batch. Returns int32 ``[S]``.
     """
-    with jax.named_scope("sample"):
-        return jax.vmap(_sample_row)(
-            logits, temperature.astype(jnp.float32),
+    args = (logits, temperature.astype(jnp.float32),
             top_k.astype(jnp.int32), top_p.astype(jnp.float32),
             seed.astype(jnp.int32), position.astype(jnp.int32))
+    with jax.named_scope("sample"):
+        return jax.lax.switch(sample_path(args[1]), _BRANCHES, *args)
 
 
 def normalize_stops(stop) -> Tuple[Tuple[int, ...], ...]:
